@@ -48,8 +48,6 @@ def _xla_bytes_accessed(jitted, *args):
     materializes), unlike the analytic roofline model. None if unavailable."""
     try:
         cost = jitted.lower(*args).compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):  # jax 0.4.x returns a list
-            cost = cost[0] if cost else {}
         b = cost.get("bytes accessed")
         return float(b) if b is not None else None
     except Exception:

@@ -125,7 +125,7 @@ def live_device_bytes() -> int:
     import numpy as np
 
     total = 0
-    for a in jax.live_arrays() if hasattr(jax, "live_arrays") else []:
+    for a in jax.live_arrays():
         try:
             total += int(np.prod(a.shape)) * a.dtype.itemsize
         except Exception:

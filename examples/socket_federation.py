@@ -68,7 +68,11 @@ def _base_cmd(args):
 
 
 def _env():
+    # every child imports JAX; a chip admits one process, so all of them (the
+    # server, the workers and the in-process reference) run on the CPU, which
+    # also keeps the bitwise socket-vs-inproc comparison on one backend
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(ROOT, "src") + os.pathsep + env.get(
         "PYTHONPATH", ""
     )

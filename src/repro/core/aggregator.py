@@ -308,7 +308,6 @@ class SyncAggregator(Aggregator):
         self.pcfg = pcfg
         self.codec = codec
         self.seed = seed
-        self.partial_progress = pcfg.partial_progress
         self.fused_server = fused_server
         if cohort_tile is not None:
             cohort_tile = int(cohort_tile)
@@ -447,41 +446,18 @@ class SyncAggregator(Aggregator):
             return
         self._tile_fn = self._apply_partial_fn = None
         self._fold_update_fn = self._fold_finish_fn = self._tile_clip_fn = None
+        # one round program: the residual rows are None without a stateful
+        # codec, and the τ-vector is all-full without partial progress
         donate = (0, 3) if stateful else (0,)
         donate_kw = {"donate_argnums": donate} if self.donate else {}
-        if self.partial_progress and stateful:
-            self._round_fn = jax.jit(
-                lambda s, b, w, res, tau: federated_round(
-                    loss_fn, fed, s, b, client_weights=w, codec=codec,
-                    residuals=res, shard_clients=shard_clients, tau_steps=tau,
-                    apply_fn=apply_fn,
-                ),
-                **donate_kw,
-            )
-        elif self.partial_progress:
-            self._round_fn = jax.jit(
-                lambda s, b, w, tau: federated_round(
-                    loss_fn, fed, s, b, client_weights=w, codec=codec,
-                    shard_clients=shard_clients, tau_steps=tau, apply_fn=apply_fn,
-                ),
-                **donate_kw,
-            )
-        elif stateful:
-            self._round_fn = jax.jit(
-                lambda s, b, w, res: federated_round(
-                    loss_fn, fed, s, b, client_weights=w, codec=codec,
-                    residuals=res, shard_clients=shard_clients, apply_fn=apply_fn,
-                ),
-                **donate_kw,
-            )
-        else:
-            self._round_fn = jax.jit(
-                lambda s, b, w: federated_round(
-                    loss_fn, fed, s, b, client_weights=w, codec=codec,
-                    shard_clients=shard_clients, apply_fn=apply_fn,
-                ),
-                **donate_kw,
-            )
+        self._round_fn = jax.jit(
+            lambda s, b, w, res, tau: federated_round(
+                loss_fn, fed, s, b, client_weights=w, codec=codec,
+                residuals=res, shard_clients=shard_clients, tau_steps=tau,
+                apply_fn=apply_fn,
+            ),
+            **donate_kw,
+        )
 
     def apply_knobs(self, update) -> None:
         """Apply a sync :class:`KnobUpdate` between rounds.
@@ -527,14 +503,15 @@ class SyncAggregator(Aggregator):
             plan.weights, plan.local_steps, self.fed.local_steps
         )
 
-    def tau_steps(self, plan: ParticipationPlan) -> Optional[np.ndarray]:
-        """The (K,) τ-mask handed to the jitted round. Masked (zero-weight)
-        slots keep the FULL τ so their lanes compute exactly what the
-        non-partial round computed (their output is weight-masked anyway) —
-        this is what keeps 'everyone at full speed' bitwise identical even
-        when dropout masks part of the cohort."""
+    def tau_steps(self, plan: ParticipationPlan) -> np.ndarray:
+        """The (K,) τ-mask handed to the jitted round: all-full τ without
+        partial progress. Masked (zero-weight) slots keep the FULL τ so their
+        lanes compute exactly what the non-partial round computed (their
+        output is weight-masked anyway) — this is what keeps 'everyone at
+        full speed' bitwise identical even when dropout masks part of the
+        cohort."""
         if plan.local_steps is None:
-            return None
+            return np.full(len(plan.selected), self.fed.local_steps, np.int32)
         return np.where(
             plan.mask, plan.local_steps, self.fed.local_steps
         ).astype(np.int32)
@@ -592,12 +569,10 @@ class SyncAggregator(Aggregator):
         error-feedback rows around it (bitwise the old in-graph dense
         take/set — the gathered values are identical)."""
         stateful = self.residual_store is not None
-        args = [self.state, batches, w]
-        if stateful:
-            args.append(self.residual_store.gather(plan.selected))
-        if self.partial_progress:
-            args.append(jnp.asarray(self.tau_steps(plan), jnp.int32))
-        self.state, metrics = self._round_fn(*args)
+        res = self.residual_store.gather(plan.selected) if stateful else None
+        self.state, metrics = self._round_fn(
+            self.state, batches, w, res, jnp.asarray(self.tau_steps(plan))
+        )
         if stateful:
             # `federated_round` returns the cohort's updated rows in-state;
             # they belong in the population store, not the jitted state
@@ -623,10 +598,7 @@ class SyncAggregator(Aggregator):
         n_tiles = -(-C // ct)
         stateful = self.residual_store is not None
         w_np = np.asarray(w, np.float32)
-        tau_np = (
-            np.asarray(self.tau_steps(plan), np.int32)
-            if self.partial_progress else None
-        )
+        tau_np = self.tau_steps(plan)
         w_full = np.zeros(n_tiles * ct, np.float32)
         w_full[:C] = w_np
         core = {"params": self.state["params"], "round": self.state["round"]}
@@ -662,17 +634,15 @@ class SyncAggregator(Aggregator):
                 res_t = jax.tree_util.tree_map(
                     _pad, self.residual_store.gather(plan.selected[lo:hi])
                 )
-            tau_t = None
-            if tau_np is not None:
-                # pad slots take the FULL τ (the tau_steps() discipline: their
-                # output is weight-masked anyway, and full-τ lanes keep the
-                # non-partial bitwise identity)
-                tau_t = jnp.asarray(
-                    np.concatenate(
-                        [tau_np[lo:hi],
-                         np.full(ct - n_real, self.fed.local_steps, np.int32)]
-                    )
+            # pad slots take the FULL τ (the tau_steps() discipline: their
+            # output is weight-masked anyway, and full-τ lanes keep the
+            # non-partial bitwise identity)
+            tau_t = jnp.asarray(
+                np.concatenate(
+                    [tau_np[lo:hi],
+                     np.full(ct - n_real, self.fed.local_steps, np.int32)]
                 )
+            )
             s_t = dict(core, rng=tile_rng(base_rng, t_idx))
             out = self._tile_fn(s_t, b_t, w_t, res_t, tau_t)
             if stateful:

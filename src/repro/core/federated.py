@@ -197,10 +197,9 @@ def run_clients(
     realized step counts τ_i ≤ τ. The scan still runs all τ iterations, but a
     client whose budget is spent (t ≥ τ_i) holds its params and inner state
     frozen via an in-graph ``where`` — so a slow client's delta reflects exactly
-    the τ_i steps it finished, no recompile happens when the τ_i vector changes
-    round to round, and an all-full vector (τ_i = τ everywhere) is bitwise
-    identical to ``tau_steps=None`` (``where(True, new, old)`` returns ``new``
-    exactly — the same discipline as the elastic weight mask).
+    the τ_i steps it finished, and no recompile happens when the τ_i vector
+    changes round to round. ``tau_steps=None`` IS the all-full vector (τ_i = τ
+    everywhere): there is one masked path, so the two agree by construction.
 
     Pure in ``(state, batches, weights, residuals)``; shared verbatim by the
     synchronous round and the async buffered path (``core/async_agg``), so the two
@@ -239,6 +238,9 @@ def run_clients(
         inner_states = jax.vmap(lambda p: init_inner_state(fed.inner, p))(client_params)
 
     seq_step0 = state["round"].astype(jnp.int32) * fed.local_steps
+    if tau_steps is None:
+        tau_steps = jnp.full((C,), fed.local_steps, jnp.int32)
+    tau_steps = tau_steps.astype(jnp.int32)
 
     def local_step(carry, batch_t):
         params_c, inner_c, t = carry
@@ -263,48 +265,40 @@ def run_clients(
         new_params_c, new_inner_c, metrics_c = jax.vmap(one_client)(
             params_c, inner_c, batch_t
         )
-        if tau_steps is not None:
-            # partial progress: clients whose step budget is spent hold their
-            # params/inner state (the masked scan lanes still execute, their
-            # results are discarded — exactly the elastic-weights discipline)
-            active = t < tau_steps.astype(jnp.int32)  # (C,)
+        # partial progress: clients whose step budget is spent hold their
+        # params/inner state (the masked scan lanes still execute, their
+        # results are discarded — exactly the elastic-weights discipline)
+        active = t < tau_steps  # (C,)
 
-            def _hold(new, old):
-                return jnp.where(
-                    active.reshape((-1,) + (1,) * (new.ndim - 1)), new, old
-                )
+        def _hold(new, old):
+            return jnp.where(
+                active.reshape((-1,) + (1,) * (new.ndim - 1)), new, old
+            )
 
-            new_params_c = jax.tree_util.tree_map(_hold, new_params_c, params_c)
-            new_inner_c = jax.tree_util.tree_map(_hold, new_inner_c, inner_c)
-            act = active.astype(jnp.float32)
-            # metrics weighted over the clients actually stepping at time t
-            # (all-active: part·1.0 ≡ part, so this recomputes metric_w exactly)
-            raw_w = part * act if elastic else act
-            n_active = jnp.sum(raw_w)
-            step_w = raw_w / jnp.maximum(n_active, 1.0)
-            step_metrics = {k: jnp.sum(v * step_w) for k, v in metrics_c.items()}
-            step_metrics["_n_active"] = n_active
-        elif elastic:  # don't let masked clients' losses pollute the round metrics
-            step_metrics = {k: jnp.sum(v * metric_w) for k, v in metrics_c.items()}
-        else:
-            step_metrics = {k: jnp.mean(v) for k, v in metrics_c.items()}
+        new_params_c = jax.tree_util.tree_map(_hold, new_params_c, params_c)
+        new_inner_c = jax.tree_util.tree_map(_hold, new_inner_c, inner_c)
+        act = active.astype(jnp.float32)
+        # metrics weighted over the clients actually stepping at time t, so
+        # masked clients' losses never pollute the round metrics
+        raw_w = part * act if elastic else act
+        n_active = jnp.sum(raw_w)
+        step_w = raw_w / jnp.maximum(n_active, 1.0)
+        step_metrics = {k: jnp.sum(v * step_w) for k, v in metrics_c.items()}
+        step_metrics["_n_active"] = n_active
         return (new_params_c, new_inner_c, t + 1), step_metrics
 
     (client_params, inner_states, _), step_metrics = jax.lax.scan(
         local_step, (client_params, inner_states, jnp.zeros((), jnp.int32)), batches
     )
-    if tau_steps is not None:
-        # DEAD steps — every weighted client past its τ_i — reduced over an
-        # empty set above: forward-fill each such step from the last step that
-        # had an active client, so step_metrics[-1] is "the last training
-        # signal observed" and the per-step series is never zero-diluted. With
-        # every client at full τ no step is dead and the gather returns the
-        # series untouched (bitwise — the tau_steps=None identity survives).
-        n_active = step_metrics.pop("_n_active")  # (τ,)
-        t_idx = jnp.arange(n_active.shape[0], dtype=jnp.int32)
-        last_live = jax.lax.cummax(jnp.where(n_active > 0, t_idx, -1))
-        last_live = jnp.maximum(last_live, 0)  # step 0 is always live (τ_i ≥ 1)
-        step_metrics = {k: v[last_live] for k, v in step_metrics.items()}
+    # DEAD steps — every weighted client past its τ_i — reduced over an empty
+    # set above: forward-fill each such step from the last step that had an
+    # active client, so step_metrics[-1] is "the last training signal
+    # observed" and the per-step series is never zero-diluted
+    n_active = step_metrics.pop("_n_active")  # (τ,)
+    t_idx = jnp.arange(n_active.shape[0], dtype=jnp.int32)
+    last_live = jax.lax.cummax(jnp.where(n_active > 0, t_idx, -1))
+    last_live = jnp.maximum(last_live, 0)  # step 0 is always live (τ_i ≥ 1)
+    step_metrics = {k: v[last_live] for k, v in step_metrics.items()}
 
     if fed.keep_inner_state and elastic:
         # masked clients never actually ran this round: keep their previous inner
@@ -610,8 +604,8 @@ def federated_round(
 
     ``tau_steps`` enables straggler partial progress (see :func:`run_clients`);
     the caller's weight policy (``core/aggregator``) is expected to scale the
-    weights by τ_i/τ so a partial delta is credited fractionally. An all-full
-    τ-vector is bitwise ``tau_steps=None``.
+    weights by τ_i/τ so a partial delta is credited fractionally.
+    ``tau_steps=None`` is the all-full τ-vector.
 
     ``client_weights`` makes the round *elastic*: a (C,) vector of aggregation
     weights (e.g. FedAvg data sizes from a ``ParticipationPlan``), where a zero

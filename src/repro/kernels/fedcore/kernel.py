@@ -36,24 +36,13 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_COMPILER_PARAMS = (
-    getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams", None)
-    if pltpu is not None
-    else None
-)
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _compiler_params(interpret: bool, semantics: Tuple[str, ...]):
-    if interpret or _COMPILER_PARAMS is None:
+    if interpret:
         return None
-    return _COMPILER_PARAMS(dimension_semantics=semantics)
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 # ---------------------------------------------------------------------------
@@ -126,15 +115,17 @@ def _server_apply_kernel(
     for lane, o_ref in zip(new_lanes, o_lane_refs):
         o_ref[...] = lane.astype(o_ref.dtype)
 
+    # the accumulators are stored as whole (1,1)/(C,1) blocks: the TPU compiler
+    # refuses scalar stores into VMEM
     @pl.when(i == 0)
     def _():
-        pg_sq_ref[0, 0] = 0.0
-        np_sq_ref[0, 0] = 0.0
+        pg_sq_ref[...] = jnp.zeros_like(pg_sq_ref)
+        np_sq_ref[...] = jnp.zeros_like(np_sq_ref)
         dsq_ref[...] = jnp.zeros_like(dsq_ref)
 
-    pg_sq_ref[0, 0] += jnp.sum(jnp.square(pg))
+    pg_sq_ref[...] += jnp.sum(jnp.square(pg)).reshape(1, 1)
     # norm of the params as STORED (post-cast), matching the ref's global_norm
-    np_sq_ref[0, 0] += jnp.sum(jnp.square(new_p_cast.astype(jnp.float32)))
+    np_sq_ref[...] += jnp.sum(jnp.square(new_p_cast.astype(jnp.float32))).reshape(1, 1)
     dsq_ref[...] += jnp.sum(jnp.square(d), axis=1, keepdims=True)
 
 

@@ -16,10 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU compiler params are optional under interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -123,7 +120,7 @@ def flash_attention_fwd(
     o_spec = pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0))
 
     compiler_params = None
-    if pltpu is not None and not interpret:
+    if not interpret:
         compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         )
@@ -135,16 +132,10 @@ def flash_attention_fwd(
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            _vmem((block_q,), jnp.float32),
-            _vmem((block_q,), jnp.float32),
-            _vmem((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         compiler_params=compiler_params,
         interpret=interpret,
     )(q, k, v)
-
-
-def _vmem(shape, dtype):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemorySpace.ANY(shape, dtype)  # pragma: no cover

@@ -15,10 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -104,7 +101,7 @@ def flash_decode_fwd(
     o_spec = pl.BlockSpec((1, 1, hd), lambda b, h, j: (b, h, 0))
 
     compiler_params = None
-    if pltpu is not None and not interpret:
+    if not interpret:
         compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )

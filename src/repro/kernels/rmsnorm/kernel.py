@@ -12,10 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
@@ -37,7 +34,7 @@ def rmsnorm_fwd(
     assert R % block_rows == 0, (R, block_rows)
     kernel = functools.partial(_rmsnorm_kernel, eps=eps)
     compiler_params = None
-    if pltpu is not None and not interpret:
+    if not interpret:
         compiler_params = pltpu.CompilerParams(dimension_semantics=("parallel",))
     return pl.pallas_call(
         kernel,

@@ -15,10 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_kernel(
@@ -114,7 +111,7 @@ def ssd_scan_fwd(
     fin_spec = pl.BlockSpec((1, 1, hd, ds), lambda b, h, c: (b, h, 0, 0))
 
     compiler_params = None
-    if pltpu is not None and not interpret:
+    if not interpret:
         compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )

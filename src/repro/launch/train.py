@@ -156,6 +156,7 @@ from repro.core import (
     plan_round,
 )
 from repro.data import build_client_streams, round_batches, validation_stream
+from repro.launch.compile_env import CompileCounter, enable_compile_cache
 from repro.metrics import (
     MetricLogger,
     evaluate_perplexity,
@@ -750,10 +751,11 @@ def run(args, cfg=None) -> dict:
 
     history = []
     try:
-        _run_sync_rounds(
-            args, model, agg, streams, val_stream, ckpt, logger, history,
-            start_round, params, codec,
-        )
+        with CompileCounter() as compiles:
+            _run_sync_rounds(
+                args, model, agg, streams, val_stream, ckpt, logger, history,
+                start_round, params, codec, compiles,
+            )
     finally:
         if metrics_srv is not None:
             metrics_srv.close()
@@ -765,9 +767,10 @@ def run(args, cfg=None) -> dict:
 
 
 def _run_sync_rounds(args, model, agg, streams, val_stream, ckpt, logger,
-                     history, start_round, params, codec):
+                     history, start_round, params, codec, compiles):
     for rnd in range(start_round, args.rounds):
         t0 = time.perf_counter()  # monotonic: durations, never wall timestamps
+        c0 = compiles.count
         plan = agg.plan(rnd)
         sel = plan.selected
         batches_np = round_batches([streams[i] for i in sel], args.local_steps, args.batch)
@@ -792,6 +795,9 @@ def _run_sync_rounds(args, model, agg, streams, val_stream, ckpt, logger,
             batch_size=args.batch,
         )
         metrics["val_ppl"] = val_ppl
+        # XLA compilations this round triggered (round step, eval, eager ops):
+        # nonzero after the first round means something recompiles
+        metrics["compiles"] = compiles.count - c0
         history.append(metrics)
         partial = (
             f" tau={metrics['partial_tau_mean']:.2f} "
@@ -1110,6 +1116,8 @@ def _run_async(args, cfg, model, fed, pcfg, streams, val_stream, params, codec=N
     history = []
     deltas_admitted = [deltas_resumed]
     t_wall = [time.perf_counter()]  # monotonic: row["seconds"] is a duration
+    compiles = CompileCounter()
+    seen_compiles = [0]
 
     def on_update(i, row):
         u = start_update + i  # absolute outer-update index across resumes
@@ -1146,6 +1154,9 @@ def _run_async(args, cfg, model, fed, pcfg, streams, val_stream, params, codec=N
             model, driver.state["params"], val_stream,
             batches=args.eval_batches, batch_size=args.batch,
         )
+        # compilations since the previous update (first row: since start)
+        row["compiles"] = compiles.count - seen_compiles[0]
+        seen_compiles[0] = compiles.count
         history.append(row)
         print(
             f"update {u}: loss={row['train_loss_mean']:.4f} "
@@ -1227,7 +1238,10 @@ def _run_async(args, cfg, model, fed, pcfg, streams, val_stream, params, codec=N
 
     try:
         if args.rounds > start_update:
-            driver.run_updates(args.rounds - start_update, on_update=on_update)
+            with compiles:
+                driver.run_updates(
+                    args.rounds - start_update, on_update=on_update
+                )
         else:
             print(f"nothing to do: checkpoint already at update {start_update - 1} "
                   f"of {args.rounds}")
@@ -1244,6 +1258,7 @@ def _run_async(args, cfg, model, fed, pcfg, streams, val_stream, params, codec=N
 
 
 def main() -> None:
+    enable_compile_cache()
     run(parse_args())
 
 

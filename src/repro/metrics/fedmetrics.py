@@ -8,7 +8,6 @@ import math
 import os
 from typing import Dict, Iterable, List, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -244,11 +243,10 @@ def uplink_round_metrics(
 
 def evaluate_perplexity(model, params, stream, batches: int = 4, batch_size: int = 4) -> float:
     """Held-out perplexity on a validation stream (server-side evaluation, §4.2)."""
-    loss_fn = jax.jit(lambda p, b: model.loss(p, b)[1]["ce"])
     total, n = 0.0, 0
     for _ in range(batches):
         tokens = jnp.asarray(stream.next_batch(batch_size))
-        total += float(loss_fn(params, {"tokens": tokens}))
+        total += float(model.eval_ce(params, {"tokens": tokens}))
         n += 1
     return perplexity(total / n)
 
@@ -256,7 +254,7 @@ def evaluate_perplexity(model, params, stream, batches: int = 4, batch_size: int
 def activation_l2_probe(model, params, batch) -> float:
     """L2 norm of output logits activations — the divergence leading indicator the
     paper tracks (Fig 5)."""
-    logits, _, _ = jax.jit(lambda p, b: model.forward(p, b))(params, batch)
+    logits, _, _ = model.jit_forward(params, batch)
     return float(jnp.sqrt(jnp.mean(jnp.square(logits.astype(jnp.float32)))))
 
 

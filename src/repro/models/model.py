@@ -7,6 +7,7 @@
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -179,6 +180,18 @@ class Model:
             metrics["moe_aux"] = aux
         metrics["loss"] = loss
         return loss, metrics
+
+    # -- jitted evaluation, built once per model -------------------------
+    @functools.cached_property
+    def eval_ce(self):
+        """Jitted ``(params, batch) -> mean cross-entropy``. One function per
+        model, so repeated evaluations reuse its compiled executables."""
+        return jax.jit(lambda p, b: self.loss(p, b)[1]["ce"])
+
+    @functools.cached_property
+    def jit_forward(self):
+        """Jitted ``forward(params, batch)``, one per model."""
+        return jax.jit(lambda p, b: self.forward(p, b))
 
     # -- serving ----------------------------------------------------------
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16):

@@ -172,8 +172,6 @@ def analyze_compiled(name: str, compiled, chips: int, model_flops: Optional[floa
     from repro.roofline.hlo_analyzer import analyze as hlo_analyze
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # some jax versions return [dict]
-        cost = cost[0]
     text = compiled.as_text()
     hlo = hlo_analyze(text)  # trip-count-aware (XLA counts while bodies once)
     flops = hlo.flops

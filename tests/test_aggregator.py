@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 from conftest import make_batches, make_params, quad_loss, sgd_inner
 
 from repro.checkpoint import CheckpointManager
@@ -135,34 +136,29 @@ def test_partial_progress_weight_policy():
     np.testing.assert_array_equal(partial_progress_weights(w, None, 4), w)
 
 
-try:
-    from hypothesis import given, settings, strategies as st
-
-    @given(
-        n=st.integers(2, 12),
-        tau=st.integers(1, 32),
-        seed=st.integers(0, 10_000),
-    )
-    @settings(max_examples=25, deadline=None)
-    def test_partial_weights_are_convex_normalization(n, tau, seed):
-        """Normalized partial-progress weights form a convex combination:
-        Σw = 1, w_i ∝ n_k,i·τ_i/τ, and zero exactly where masked."""
-        rng = np.random.default_rng(seed)
-        n_k = rng.lognormal(0.0, 1.0, n).astype(np.float32)
-        mask = rng.random(n) < 0.7
-        if not mask.any():
-            mask[int(rng.integers(n))] = True
-        ls = np.where(mask, rng.integers(1, tau + 1, n), 0)
-        raw = (n_k * mask).astype(np.float32)
-        w = partial_progress_weights(raw, ls, tau)
-        assert (w[~mask] == 0).all()
-        assert (w[mask] > 0).all()
-        p = np.asarray(w, np.float64) / np.sum(w, dtype=np.float64)
-        np.testing.assert_allclose(p.sum(), 1.0, rtol=1e-9)
-        ref = n_k * mask * (ls / tau)
-        np.testing.assert_allclose(p, ref / ref.sum(), rtol=1e-4, atol=1e-7)
-except ImportError:  # pragma: no cover — optional dep
-    pass
+@given(
+    n=st.integers(2, 12),
+    tau=st.integers(1, 32),
+    seed=st.integers(0, 10_000),
+)
+@settings(max_examples=25, deadline=None)
+def test_partial_weights_are_convex_normalization(n, tau, seed):
+    """Normalized partial-progress weights form a convex combination:
+    Σw = 1, w_i ∝ n_k,i·τ_i/τ, and zero exactly where masked."""
+    rng = np.random.default_rng(seed)
+    n_k = rng.lognormal(0.0, 1.0, n).astype(np.float32)
+    mask = rng.random(n) < 0.7
+    if not mask.any():
+        mask[int(rng.integers(n))] = True
+    ls = np.where(mask, rng.integers(1, tau + 1, n), 0)
+    raw = (n_k * mask).astype(np.float32)
+    w = partial_progress_weights(raw, ls, tau)
+    assert (w[~mask] == 0).all()
+    assert (w[mask] > 0).all()
+    p = np.asarray(w, np.float64) / np.sum(w, dtype=np.float64)
+    np.testing.assert_allclose(p.sum(), 1.0, rtol=1e-9)
+    ref = n_k * mask * (ls / tau)
+    np.testing.assert_allclose(p, ref / ref.sum(), rtol=1e-4, atol=1e-7)
 
 
 # ---------------------------------------------------------------------------
@@ -256,8 +252,12 @@ def test_partial_client_delta_equals_truncated_round():
     deltas_short, _ = run_clients(
         quad_loss, fed_short, init_federated_state(fed_short, params), short_b
     )
-    np.testing.assert_array_equal(
-        np.asarray(deltas["w"][0]), np.asarray(deltas_short["w"][0])
+    # a τ-step scan and a τ_i-step scan are two different XLA programs, which
+    # may fuse the same arithmetic differently: agree to a few float32 ulps
+    short = np.asarray(deltas_short["w"][0])
+    ulp_tol = 4 * np.finfo(np.float32).eps * np.abs(short).max()
+    np.testing.assert_allclose(
+        np.asarray(deltas["w"][0]), short, rtol=0, atol=ulp_tol
     )
     # the full-τ clients are untouched by their neighbors' masks
     full_deltas, _ = run_clients(quad_loss, fed, s0, batches)

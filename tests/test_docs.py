@@ -7,9 +7,10 @@ properties — no imports, no jax — so this lane is fast and runs blocking.
 
 Three invariants:
 
-1. every ``--flag`` token in ``docs/*.md`` and in the ``examples/*.py``
-   module docstrings is defined by SOME argparse parser in the repo's
-   entry-point sources (train/dryrun/report, the examples, the bench runner);
+1. every ``--flag`` token in ``docs/*.md``, ``README.md`` and the
+   ``examples/*.py`` module docstrings is defined by SOME argparse parser in
+   the repo's entry-point sources (train/dryrun/report, the examples, the
+   bench runner, ``chip_smoke.py``);
 2. every relative markdown link inside ``docs/`` resolves to a git-tracked
    file;
 3. every doc under ``docs/`` is reachable from the ``docs/architecture.md``
@@ -34,6 +35,7 @@ PARSER_SOURCES = [
     REPO / "src" / "repro" / "launch" / "dryrun.py",
     REPO / "src" / "repro" / "obs" / "report.py",
     REPO / "benchmarks" / "run.py",
+    REPO / "chip_smoke.py",
     *sorted((REPO / "examples").glob("*.py")),
 ]
 
@@ -92,6 +94,11 @@ def test_doc_flags_exist(md):
     assert not unknown, (
         f"{md.name} references flags no entry-point parser defines: {unknown}"
     )
+
+
+def test_readme_flags_exist():
+    unknown = _unknown_flags((REPO / "README.md").read_text(), _defined_flags())
+    assert not unknown, f"README.md references undefined flags: {unknown}"
 
 
 @pytest.mark.parametrize("py", EXAMPLE_FILES, ids=lambda p: p.name)

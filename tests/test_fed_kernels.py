@@ -91,7 +91,6 @@ def _assert_trees_equal(a, b):
 def test_pack_unpack_roundtrip_bitwise_property():
     """Hypothesis property: for arbitrary leaf shape lists and pad multiples,
     pack → unpack is a BITWISE pytree round-trip and padding is zero."""
-    hyp = pytest.importorskip("hypothesis")
     from hypothesis import given, settings, strategies as st
 
     shapes_st = st.lists(

@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis")  # optional dep: skip cleanly where absent
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (

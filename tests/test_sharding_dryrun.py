@@ -81,11 +81,8 @@ from repro.configs import get_config
 from repro.launch.steps import build_step
 from repro.roofline import analyze_compiled
 
-try:  # AxisType landed after jax 0.4.x; older versions default to Auto anyway
-    from jax.sharding import AxisType
-    mesh = jax.make_mesh({mesh_shape}, {mesh_axes}, axis_types=(AxisType.Auto,) * {n_axes})
-except ImportError:
-    mesh = jax.make_mesh({mesh_shape}, {mesh_axes})
+from jax.sharding import AxisType
+mesh = jax.make_mesh({mesh_shape}, {mesh_axes}, axis_types=(AxisType.Auto,) * {n_axes})
 cfg = get_config("{arch}").reduced()
 with mesh:
     step = build_step(cfg, "{shape}", mesh, **{kw})
@@ -233,11 +230,8 @@ from repro.configs import get_config, INPUT_SHAPES
 from repro.launch.steps import build_train_step
 from repro.roofline import analyze_compiled
 
-try:
-    from jax.sharding import AxisType
-    mesh = jax.make_mesh((4, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
-except ImportError:
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 cfg = get_config("qwen3-1.7b").reduced()
 with mesh:
     step = build_train_step(cfg, INPUT_SHAPES["train_4k"], mesh, **{kw})

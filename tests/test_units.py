@@ -282,6 +282,51 @@ def test_analyzer_matches_xla_on_scanfree_graph():
         jax.ShapeDtypeStruct((64, 32), jnp.float32),
     ).compile()
     r = analyze(c.as_text())
-    ca = c.cost_analysis()  # list-of-dicts on jax<=0.4.x, plain dict afterwards
-    xla = (ca[0] if isinstance(ca, (list, tuple)) else ca)["flops"]
+    xla = c.cost_analysis()["flops"]
     assert abs(r.flops - xla) / xla < 0.1
+
+
+def test_evaluate_perplexity_compiles_once_per_model(tiny_model):
+    """The eval forward is jitted once per model: a second evaluation (the
+    next round's) reuses the compiled executable instead of recompiling."""
+    from repro.data import validation_stream
+    from repro.launch.compile_env import CompileCounter
+    from repro.metrics import activation_l2_probe, evaluate_perplexity
+    from repro.models import build_model
+
+    cfg, _, params = tiny_model
+    model = build_model(cfg)  # fresh: nothing compiled for it yet
+    val = validation_stream(16, cfg.vocab_size, False)
+    with CompileCounter() as first:
+        ppl0 = evaluate_perplexity(model, params, val, batches=2, batch_size=2)
+    with CompileCounter() as second:
+        ppl1 = evaluate_perplexity(model, params, val, batches=2, batch_size=2)
+    assert first.count >= 1
+    assert second.count == 0
+    assert np.isfinite(ppl0) and np.isfinite(ppl1)
+    batch = {"tokens": jnp.asarray(val.next_batch(2))}
+    activation_l2_probe(model, params, batch)
+    with CompileCounter() as probe:
+        activation_l2_probe(model, params, batch)
+    assert probe.count == 0
+
+
+@pytest.mark.parametrize("env_dir", [None, "placed"])
+def test_compile_cache_dir_placement(tmp_path, monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR places the cache; without it the cache is the
+    fixed <checkout>/.jax_cache."""
+    from repro.launch.compile_env import CHECKOUT_ROOT, enable_compile_cache
+
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        expect = str(CHECKOUT_ROOT / ".jax_cache")
+    else:
+        expect = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", expect)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        assert enable_compile_cache() == expect
+        assert jax.config.jax_compilation_cache_dir == expect
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    assert (CHECKOUT_ROOT / "src" / "repro" / "launch" / "compile_env.py").exists()
