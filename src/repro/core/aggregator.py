@@ -85,6 +85,7 @@ from repro.core.robust import (
     tile_fold_size,
     tile_fold_update,
 )
+from repro.obs import programs
 from repro.obs.metrics import observe_staleness
 from repro.obs.tracer import get_tracer
 from repro.core.sampler import (
@@ -386,48 +387,53 @@ class SyncAggregator(Aggregator):
             # the default path keeps the memory-minimal partial-sum-only output
             return_deltas = robust_tiled
 
-            def _tile(s, b, w, res, tau):
+            def fed_round_tile(s, b, w, res, tau):
                 return run_client_tile(
                     loss_fn, fed_tile, s, b, w, shard_clients=shard_clients,
                     codec=codec, residuals=res, tau_steps=tau,
                     return_deltas=return_deltas,
                 )
 
-            self._tile_fn = jax.jit(_tile, **donate_kw)
+            def fed_round_server(s, dsum, w, dn):
+                with jax.named_scope("server"):
+                    return apply_aggregate_partial(fed, s, dsum, w, dn)
+
+            self._tile_fn = programs.register(jax.jit(fed_round_tile, **donate_kw))
             # donate the server state only: the Σ wΔ partial sums feed the
             # pseudo-gradient metrics as well as the update, so XLA cannot
             # alias their buffers (donating them would just warn)
-            self._apply_partial_fn = jax.jit(
-                lambda s, dsum, w, dn: apply_aggregate_partial(fed, s, dsum, w, dn),
+            self._apply_partial_fn = programs.register(jax.jit(
+                fed_round_server,
                 **({"donate_argnums": (0,)} if self.donate else {}),
-            )
+            ))
             self._fold_update_fn = self._fold_finish_fn = None
             self._tile_clip_fn = None
             if robust_tiled and robust.rule in ("trimmed", "median"):
                 rule, trim = robust.rule, robust.trim_fraction
 
-                def _fold_update(fold, deltas, norms, w):
+                def fed_round_fold(fold, deltas, norms, w):
                     admit = (w > 0) & jnp.isfinite(norms)
                     return tile_fold_update(
                         fold, sanitize_deltas(deltas, jnp.isfinite(norms)), admit
                     )
 
-                def _fold_finish(fold, s, dn, w):
-                    pg = tile_fold_finish(fold, rule, trim)
-                    return _finish_aggregate(fed, s, pg, dn, w)
+                def fed_round_fold_server(fold, s, dn, w):
+                    with jax.named_scope("server"):
+                        pg = tile_fold_finish(fold, rule, trim)
+                        return _finish_aggregate(fed, s, pg, dn, w)
 
-                self._fold_update_fn = jax.jit(
-                    _fold_update,
+                self._fold_update_fn = programs.register(jax.jit(
+                    fed_round_fold,
                     **({"donate_argnums": (0,)} if self.donate else {}),
-                )
-                self._fold_finish_fn = jax.jit(
-                    _fold_finish,
+                ))
+                self._fold_finish_fn = programs.register(jax.jit(
+                    fed_round_fold_server,
                     **({"donate_argnums": (1,)} if self.donate else {}),
-                )
+                ))
             elif robust_tiled and robust.rule == "normclip":
                 tau_clip = float(robust.clip_norm)  # absolute-only with tiles
 
-                def _clip_sum(deltas, norms, w):
+                def fed_round_tile_clip(deltas, norms, w):
                     admit = (w > 0) & jnp.isfinite(norms)
                     scale = normclip_scale(
                         norms, admit, jnp.asarray(tau_clip, jnp.float32)
@@ -441,7 +447,7 @@ class SyncAggregator(Aggregator):
                         clean,
                     )
 
-                self._tile_clip_fn = jax.jit(_clip_sum)
+                self._tile_clip_fn = programs.register(jax.jit(fed_round_tile_clip))
             self._round_fn = None
             return
         self._tile_fn = self._apply_partial_fn = None
@@ -450,14 +456,15 @@ class SyncAggregator(Aggregator):
         # codec, and the τ-vector is all-full without partial progress
         donate = (0, 3) if stateful else (0,)
         donate_kw = {"donate_argnums": donate} if self.donate else {}
-        self._round_fn = jax.jit(
-            lambda s, b, w, res, tau: federated_round(
+
+        def fed_round(s, b, w, res, tau):
+            return federated_round(
                 loss_fn, fed, s, b, client_weights=w, codec=codec,
                 residuals=res, shard_clients=shard_clients, tau_steps=tau,
                 apply_fn=apply_fn,
-            ),
-            **donate_kw,
-        )
+            )
+
+        self._round_fn = programs.register(jax.jit(fed_round, **donate_kw))
 
     def apply_knobs(self, update) -> None:
         """Apply a sync :class:`KnobUpdate` between rounds.

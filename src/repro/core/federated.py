@@ -146,10 +146,16 @@ def _accum_value_and_grad(loss_fn, params, batch, n_micro: int, pre_split: bool 
     bounding activation memory like DDP micro-batching. With ``pre_split`` the batch
     leaves already carry a leading (n_micro, ...) dim — required on the mesh, where
     reshaping a sharded batch dim would break GSPMD sharding propagation."""
+    def fwd(p, b):
+        # the forward pass's scope: its ops read ``.../jvp(fwd)/...`` and the
+        # backward pass's ``.../transpose(jvp(fwd))/...`` in the compiled HLO
+        with jax.named_scope("fwd"):
+            return loss_fn(p, b)
+
     if n_micro <= 1:
         if pre_split:  # (1, B, ...) -> (B, ...)
             batch = jax.tree_util.tree_map(lambda x: x[0], batch)
-        return jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
+        return jax.value_and_grad(fwd, has_aux=True)(params, batch)
 
     if pre_split:
         micro = batch
@@ -159,7 +165,7 @@ def _accum_value_and_grad(loss_fn, params, batch, n_micro: int, pre_split: bool 
         )
 
     def body(carry, mb):
-        (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, mb)
+        (loss, metrics), grads = jax.value_and_grad(fwd, has_aux=True)(params, mb)
         acc_grads, acc_loss, acc_metrics = carry
         acc_grads = jax.tree_util.tree_map(lambda a, g: a + g / n_micro, acc_grads, grads)
         acc_metrics = jax.tree_util.tree_map(
@@ -235,7 +241,10 @@ def run_clients(
     if fed.keep_inner_state:
         inner_states = state["inner"]
     else:
-        inner_states = jax.vmap(lambda p: init_inner_state(fed.inner, p))(client_params)
+        with jax.named_scope("opt"):
+            inner_states = jax.vmap(lambda p: init_inner_state(fed.inner, p))(
+                client_params
+            )
 
     seq_step0 = state["round"].astype(jnp.int32) * fed.local_steps
     if tau_steps is None:
@@ -256,9 +265,10 @@ def run_clients(
                     params,
                     global_params,
                 )
-            new_params, new_inner, opt_metrics = inner_update(
-                fed.inner, params, grads, inner, seq_step0 + t
-            )
+            with jax.named_scope("opt"):
+                new_params, new_inner, opt_metrics = inner_update(
+                    fed.inner, params, grads, inner, seq_step0 + t
+                )
             metrics = dict(metrics, **opt_metrics)
             return new_params, new_inner, metrics
 
@@ -275,8 +285,9 @@ def run_clients(
                 active.reshape((-1,) + (1,) * (new.ndim - 1)), new, old
             )
 
-        new_params_c = jax.tree_util.tree_map(_hold, new_params_c, params_c)
-        new_inner_c = jax.tree_util.tree_map(_hold, new_inner_c, inner_c)
+        with jax.named_scope("opt"):  # the hold is the update's last op
+            new_params_c = jax.tree_util.tree_map(_hold, new_params_c, params_c)
+            new_inner_c = jax.tree_util.tree_map(_hold, new_inner_c, inner_c)
         act = active.astype(jnp.float32)
         # metrics weighted over the clients actually stepping at time t, so
         # masked clients' losses never pollute the round metrics
@@ -622,14 +633,16 @@ def federated_round(
     telemetry); use :func:`federated_round_with_uplink` when the residuals live
     in a population-keyed store.
     """
-    deltas, aux = run_clients(
-        loss_fn, fed, state, batches,
-        client_weights=client_weights, shard_clients=shard_clients,
-        codec=codec, residuals=residuals, tau_steps=tau_steps,
-    )
-    new_state, agg_metrics = (apply_fn or apply_aggregate)(
-        fed, state, deltas, client_weights=client_weights, codec=codec
-    )
+    with jax.named_scope("client"):
+        deltas, aux = run_clients(
+            loss_fn, fed, state, batches,
+            client_weights=client_weights, shard_clients=shard_clients,
+            codec=codec, residuals=residuals, tau_steps=tau_steps,
+        )
+    with jax.named_scope("server"):
+        new_state, agg_metrics = (apply_fn or apply_aggregate)(
+            fed, state, deltas, client_weights=client_weights, codec=codec
+        )
 
     step_metrics = aux["step_metrics"]
     metrics = {
@@ -933,11 +946,12 @@ def run_client_tile(
             "(the (C,)-batched inner store is exactly the memory term tiling "
             "removes); use keep_inner_state=False"
         )
-    deltas, aux = run_clients(
-        loss_fn, fed, state, batches,
-        client_weights=client_weights, shard_clients=shard_clients,
-        codec=codec, residuals=residuals, tau_steps=tau_steps,
-    )
+    with jax.named_scope("client"):
+        deltas, aux = run_clients(
+            loss_fn, fed, state, batches,
+            client_weights=client_weights, shard_clients=shard_clients,
+            codec=codec, residuals=residuals, tau_steps=tau_steps,
+        )
     if codec is not None:
         deltas = jax.vmap(codec.decode)(deltas)
     w = client_weights.astype(jnp.float32)

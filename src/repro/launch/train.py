@@ -768,119 +768,160 @@ def run(args, cfg=None) -> dict:
 
 def _run_sync_rounds(args, model, agg, streams, val_stream, ckpt, logger,
                      history, start_round, params, codec, compiles):
+    """The sync loop. Each iteration is one ``iter`` span (id ``i{rnd}``) whose
+    children name what the host does in turn: ``plan``, ``data``
+    (``round_batches``), ``h2d``, ``launch`` (``agg.run_round``: the round
+    program is enqueued), ``sync`` (the metric reads, which wait for it, and
+    the row), ``eval``, ``control`` (divergence guard and controller, where
+    configured), ``ckpt`` (with ``--ckpt-dir``) and ``log``. In a profile each
+    lands on the host plane as ``obs.<name>`` (``obs/tracer.py``). The row's
+    ``seconds`` is the iteration up to its ``log``: every other child, eval
+    included."""
+    span = agg.tracer.span
+    guarded = agg.robust_state is not None and agg.robust is not None and agg.robust.rollback
     for rnd in range(start_round, args.rounds):
-        t0 = time.perf_counter()  # monotonic: durations, never wall timestamps
-        c0 = compiles.count
-        plan = agg.plan(rnd)
-        sel = plan.selected
-        batches_np = round_batches([streams[i] for i in sel], args.local_steps, args.batch)
-        batches = {k: jnp.asarray(v) for k, v in batches_np.items()}
-        metrics = agg.run_round(batches, plan)
-        metrics = {k: float(v) for k, v in metrics.items()}
-        metrics.update(
-            round=rnd,
-            selected=",".join(map(str, sel)),  # slot ids, incl. zero-weight padding
-            contributors=",".join(map(str, sel[plan.mask])),  # actually aggregated
-            seconds=time.perf_counter() - t0,
-            train_ppl=perplexity(metrics["train_loss"]),
-            **participation_metrics(plan),
-            **partial_progress_metrics(plan, args.local_steps),
-            **uplink_round_metrics(
-                args.uplink, params, plan.effective_k, args.topk_fraction,
-                codec=codec,
-            ),
-        )
-        val_ppl = evaluate_perplexity(
-            model, agg.state["params"], val_stream, batches=args.eval_batches,
-            batch_size=args.batch,
-        )
-        metrics["val_ppl"] = val_ppl
-        # XLA compilations this round triggered (round step, eval, eager ops):
-        # nonzero after the first round means something recompiles
-        metrics["compiles"] = compiles.count - c0
-        history.append(metrics)
-        partial = (
-            f" tau={metrics['partial_tau_mean']:.2f} "
-            f"rescued={metrics['partial_rescued_clients']:.0f}"
-            if args.partial_progress else ""
-        )
-        print(
-            f"round {rnd}: loss={metrics['train_loss']:.4f} val_ppl={val_ppl:.2f} "
-            f"pg_norm={metrics['pseudo_grad_norm']:.4f} "
-            f"consensus={metrics['client_consensus']:.3f} "
-            f"eff_K={plan.effective_k}/{len(plan.selected)} "
-            f"stragglers={plan.n_stragglers} dropped={plan.n_dropped}"
-            f"{partial} [{metrics['seconds']:.1f}s]"
-        )
-        # the round boundary is also the divergence-guard control point: the
-        # guard sees this round's update norm BEFORE the checkpoint save, so a
-        # poisoned round is rolled back and never becomes a resume point
-        rs = agg.robust_state
-        tripped = rolled_back = False
-        if rs is not None and agg.robust is not None and agg.robust.rollback:
-            metrics["rolled_back"] = 0.0
-            tripped = rs.observe_update(metrics["pseudo_grad_norm"])
-            if tripped:
-                good = rs.last_good
-                if good >= 0 and ckpt is not None:
-                    like = {"params": agg.state["params"],
-                            "outer": agg.state["outer"]}
-                    restored, _ = ckpt.load_server(good, like)
-                    agg.adopt_model(restored)
-                    contributors = [int(c) for c in sel[plan.mask]]
-                    rs.add_quarantine(contributors, rnd)
-                    rs.note_rollback()
-                    rolled_back = True
-                    metrics["rolled_back"] = 1.0
-                    if agg.tracer.enabled:
-                        agg.tracer.point(
-                            "rollback", round=rnd, restored_round=good,
-                            pg_norm=float(metrics["pseudo_grad_norm"])
-                            if metrics["pseudo_grad_norm"]
-                            == metrics["pseudo_grad_norm"] else -1.0,
-                            quarantined=len(contributors),
-                        )
-                        agg.tracer.count("rollbacks")
-                    print(
-                        f"  ROLLBACK: update norm "
-                        f"{metrics['pseudo_grad_norm']:.4g} tripped the "
-                        f"divergence guard — restored round {good}, "
-                        f"quarantined {contributors} for "
-                        f"{agg.robust.quarantine_rounds} rounds"
+        it = f"i{rnd}"
+        with span("iter", it, round=rnd):
+            t0 = time.perf_counter()  # monotonic: durations, never wall timestamps
+            c0, s0 = compiles.count, compiles.seconds
+            with span("plan", f"{it}/plan", it):
+                plan = agg.plan(rnd)
+            sel = plan.selected
+            with span("data", f"{it}/data", it):
+                batches_np = round_batches(
+                    [streams[i] for i in sel], args.local_steps, args.batch
+                )
+            with span("h2d", f"{it}/h2d", it):
+                batches = {k: jnp.asarray(v) for k, v in batches_np.items()}
+            with span("launch", f"{it}/launch", it):
+                metrics = agg.run_round(batches, plan)
+            with span("sync", f"{it}/sync", it):
+                metrics = {k: float(v) for k, v in metrics.items()}
+                metrics.update(
+                    round=rnd,
+                    selected=",".join(map(str, sel)),  # slot ids, incl. zero-weight padding
+                    contributors=",".join(map(str, sel[plan.mask])),  # actually aggregated
+                    train_ppl=perplexity(metrics["train_loss"]),
+                    **participation_metrics(plan),
+                    **partial_progress_metrics(plan, args.local_steps),
+                    **uplink_round_metrics(
+                        args.uplink, params, plan.effective_k, args.topk_fraction,
+                        codec=codec,
+                    ),
+                )
+            with span("eval", f"{it}/eval", it):
+                metrics["val_ppl"] = evaluate_perplexity(
+                    model, agg.state["params"], val_stream, batches=args.eval_batches,
+                    batch_size=args.batch,
+                )
+            good = True  # the round may become the divergence guard's resume point
+            if guarded or (agg.controller is not None and agg.controller.enabled):
+                with span("control", f"{it}/control", it):
+                    good = _control_sync_round(agg, ckpt, metrics, plan, rnd, guarded)
+            if ckpt:
+                with span("ckpt", f"{it}/ckpt", it):
+                    _checkpoint_sync_round(args, agg, ckpt, streams, rnd, good)
+            # XLA compilations this round triggered (round step, eval, eager
+            # ops) and their seconds: nonzero after the first round means
+            # something recompiles
+            metrics["compiles"] = compiles.count - c0
+            metrics["compile_s"] = compiles.seconds - s0
+            metrics["seconds"] = time.perf_counter() - t0
+            history.append(metrics)
+            with span("log", f"{it}/log", it):
+                partial = (
+                    f" tau={metrics['partial_tau_mean']:.2f} "
+                    f"rescued={metrics['partial_rescued_clients']:.0f}"
+                    if args.partial_progress else ""
+                )
+                print(
+                    f"round {rnd}: loss={metrics['train_loss']:.4f} "
+                    f"val_ppl={metrics['val_ppl']:.2f} "
+                    f"pg_norm={metrics['pseudo_grad_norm']:.4f} "
+                    f"consensus={metrics['client_consensus']:.3f} "
+                    f"eff_K={plan.effective_k}/{len(plan.selected)} "
+                    f"stragglers={plan.n_stragglers} dropped={plan.n_dropped}"
+                    f"{partial} [{metrics['seconds']:.1f}s]"
+                )
+                if logger:
+                    logger.log(metrics)
+
+
+def _control_sync_round(agg, ckpt, metrics, plan, rnd, guarded) -> bool:
+    """The round boundary's control point: the divergence guard, then the
+    controller. Both may add fields to ``metrics``. Returns whether the round
+    may be marked good: the guard did not trip, or it rolled the model back."""
+    # the guard sees this round's update norm BEFORE the checkpoint save, so a
+    # poisoned round is rolled back and never becomes a resume point
+    rs = agg.robust_state
+    good = True
+    if guarded:
+        metrics["rolled_back"] = 0.0
+        tripped = rs.observe_update(metrics["pseudo_grad_norm"])
+        if tripped:
+            good = False
+            last = rs.last_good
+            if last >= 0 and ckpt is not None:
+                like = {"params": agg.state["params"],
+                        "outer": agg.state["outer"]}
+                restored, _ = ckpt.load_server(last, like)
+                agg.adopt_model(restored)
+                contributors = [int(c) for c in plan.selected[plan.mask]]
+                rs.add_quarantine(contributors, rnd)
+                rs.note_rollback()
+                metrics["rolled_back"] = 1.0
+                good = True
+                if agg.tracer.enabled:
+                    agg.tracer.point(
+                        "rollback", round=rnd, restored_round=last,
+                        pg_norm=float(metrics["pseudo_grad_norm"])
+                        if metrics["pseudo_grad_norm"]
+                        == metrics["pseudo_grad_norm"] else -1.0,
+                        quarantined=len(contributors),
                     )
-                else:
-                    print(
-                        "  divergence guard tripped but no good checkpoint "
-                        "exists yet — continuing without rollback"
-                    )
-        # the round boundary is the sync control point: the cohort tuner sees
-        # this round's composed row and may move the deadline/cohort knobs for
-        # the NEXT round (applied knobs echo into the logged row)
-        update = agg.control_step(metrics)
-        if update is not None:
-            for k, v in update.knob_dict().items():
-                metrics[f"knob_{k}"] = v
-            print("  control: " + ", ".join(
-                f"{k}={v:g}" for k, v in update.knob_dict().items()
-            ))
-        if logger:
-            logger.log(metrics)
-        if ckpt:
-            if rs is not None and (not tripped or rolled_back):
-                # marked BEFORE checkpoint() so the saved manifest's last_good
-                # points at THIS round — valid exactly when this checkpoint is
-                # complete. A post-rollback checkpoint qualifies too: it holds
-                # the restored clean state (and keeps the rollback target
-                # inside the GC's keep-last window across consecutive trips)
-                rs.mark_good(rnd)
-            tree, agg_manifest = agg.checkpoint()
-            ckpt.save_server(
-                rnd, tree, extra={"args": vars(args), "aggregator": agg_manifest}
-            )
-            # every client's data cursor (unselected clients keep theirs unchanged;
-            # saving all makes any round a complete resume point)
-            for i in range(args.population):
-                ckpt.save_client(rnd, i, streams[i].state_dict())
+                    agg.tracer.count("rollbacks")
+                print(
+                    f"  ROLLBACK: update norm "
+                    f"{metrics['pseudo_grad_norm']:.4g} tripped the "
+                    f"divergence guard — restored round {last}, "
+                    f"quarantined {contributors} for "
+                    f"{agg.robust.quarantine_rounds} rounds"
+                )
+            else:
+                print(
+                    "  divergence guard tripped but no good checkpoint "
+                    "exists yet — continuing without rollback"
+                )
+    # the cohort tuner sees this round's composed row and may move the
+    # deadline/cohort knobs for the NEXT round (applied knobs echo into the
+    # logged row)
+    update = agg.control_step(metrics)
+    if update is not None:
+        for k, v in update.knob_dict().items():
+            metrics[f"knob_{k}"] = v
+        print("  control: " + ", ".join(
+            f"{k}={v:g}" for k, v in update.knob_dict().items()
+        ))
+    return good
+
+
+def _checkpoint_sync_round(args, agg, ckpt, streams, rnd, good) -> None:
+    rs = agg.robust_state
+    if rs is not None and good:
+        # marked BEFORE checkpoint() so the saved manifest's last_good points
+        # at THIS round — valid exactly when this checkpoint is complete. A
+        # post-rollback checkpoint qualifies too: it holds the restored clean
+        # state (and keeps the rollback target inside the GC's keep-last
+        # window across consecutive trips)
+        rs.mark_good(rnd)
+    tree, agg_manifest = agg.checkpoint()
+    ckpt.save_server(
+        rnd, tree, extra={"args": vars(args), "aggregator": agg_manifest}
+    )
+    # every client's data cursor (unselected clients keep theirs unchanged;
+    # saving all makes any round a complete resume point)
+    for i in range(args.population):
+        ckpt.save_client(rnd, i, streams[i].state_dict())
 
 
 # args whose value changes the pure dispatch timeline, the data every client

@@ -11,6 +11,8 @@ from typing import Dict, Iterable, List, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.metrics import STALENESS_BUCKETS
+
 
 def perplexity(loss_ce: float) -> float:
     return float(math.exp(min(30.0, loss_ce)))
@@ -104,10 +106,6 @@ def partial_progress_metrics(plan, tau: int) -> Dict[str, float]:
 # Async-aggregation monitors (FedBuff-style buffer, core/async_agg.py)
 # ---------------------------------------------------------------------------
 
-# histogram bucket edges for delta staleness (server rounds); last bucket is open
-_STALENESS_BUCKETS = ((0, 0), (1, 1), (2, 3), (4, 7), (8, None))
-
-
 def staleness_stats(staleness: Iterable[float]) -> Dict[str, float]:
     """Per-update staleness summary + histogram of the admitted deltas' ages.
 
@@ -120,7 +118,7 @@ def staleness_stats(staleness: Iterable[float]) -> Dict[str, float]:
         "staleness_mean": float(s.mean()) if s.size else 0.0,
         "staleness_max": float(s.max()) if s.size else 0.0,
     }
-    for lo, hi in _STALENESS_BUCKETS:
+    for lo, hi in STALENESS_BUCKETS:
         if hi is None:
             out[f"staleness_hist_{lo}p"] = float((s >= lo).sum())
         elif lo == hi:
@@ -132,12 +130,12 @@ def staleness_stats(staleness: Iterable[float]) -> Dict[str, float]:
 
 def staleness_hist_counts(staleness: Iterable[float]) -> np.ndarray:
     """Per-bucket counts of admitted-delta staleness, aligned with
-    ``_STALENESS_BUCKETS`` (the same buckets ``staleness_stats`` logs and the
+    ``STALENESS_BUCKETS`` (the same buckets ``staleness_stats`` logs and the
     Prometheus endpoint exports) — the cumulative-histogram input the control
     layer's staleness governor reads quantiles from."""
     s = np.asarray(list(staleness), np.float64)
     counts = []
-    for lo, hi in _STALENESS_BUCKETS:
+    for lo, hi in STALENESS_BUCKETS:
         if hi is None:
             counts.append(float((s >= lo).sum()))
         else:
@@ -157,20 +155,20 @@ def histogram_quantile(counts, q: float) -> float:
     cannot chase sub-bucket noise.
     """
     c = np.asarray(counts, np.float64)
-    if c.shape[0] != len(_STALENESS_BUCKETS):
+    if c.shape[0] != len(STALENESS_BUCKETS):
         raise ValueError(
-            f"expected {len(_STALENESS_BUCKETS)} bucket counts, got {c.shape[0]}"
+            f"expected {len(STALENESS_BUCKETS)} bucket counts, got {c.shape[0]}"
         )
     total = float(c.sum())
     if total <= 0.0:
         return 0.0
     rank = float(q) * total
     cum = 0.0
-    for (lo, hi), n in zip(_STALENESS_BUCKETS, c):
+    for (lo, hi), n in zip(STALENESS_BUCKETS, c):
         cum += float(n)
         if cum >= rank:
             return float(hi if hi is not None else lo)
-    return float(_STALENESS_BUCKETS[-1][0])  # pragma: no cover — q > 1 guard
+    return float(STALENESS_BUCKETS[-1][0])  # pragma: no cover — q > 1 guard
 
 
 def window_mean(rows, key: str, default: float = 0.0) -> float:

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import transformer
 from repro.models.common import init_params, param_axes, param_shapes
+from repro.obs import programs
 
 
 def chunked_cross_entropy(
@@ -186,7 +187,10 @@ class Model:
     def eval_ce(self):
         """Jitted ``(params, batch) -> mean cross-entropy``. One function per
         model, so repeated evaluations reuse its compiled executables."""
-        return jax.jit(lambda p, b: self.loss(p, b)[1]["ce"])
+        def eval_ce(p, b):
+            return self.loss(p, b)[1]["ce"]
+
+        return programs.register(jax.jit(eval_ce))
 
     @functools.cached_property
     def jit_forward(self):

@@ -23,13 +23,15 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, Dict, Optional
 
-from repro.metrics.fedmetrics import _STALENESS_BUCKETS
-
 from .tracer import Tracer
 
+#: Histogram buckets of delta staleness (server rounds), the last one open:
+#: ``metrics/fedmetrics.staleness_stats`` logs the same ones.
+STALENESS_BUCKETS = ((0, 0), (1, 1), (2, 3), (4, 7), (8, None))
+
 #: Cumulative upper edges of the admitted-staleness histogram, derived from
-#: the fedmetrics bucket table so CSV rows and the endpoint tell one story.
-STALENESS_EDGES = tuple(hi for _, hi in _STALENESS_BUCKETS if hi is not None)
+#: the bucket table so CSV rows and the endpoint tell one story.
+STALENESS_EDGES = tuple(hi for _, hi in STALENESS_BUCKETS if hi is not None)
 
 METRIC_PREFIX = "fed_"
 

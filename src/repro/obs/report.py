@@ -30,8 +30,6 @@ import json
 import sys
 from typing import Any, Dict, List, Sequence
 
-from repro.metrics.fedmetrics import staleness_stats
-
 from .events import Event, load_run, span_pairs
 from .export import round_rollups, write_chrome_trace
 
@@ -134,6 +132,8 @@ def straggler_breakdown(events: Sequence[Event]) -> Dict[str, Any]:
         1 for ev in events
         if ev.name == "push_recv" and ev.ph == "i" and ev.attrs.get("dup")
     )
+    from repro.metrics.fedmetrics import staleness_stats  # JAX: not at import
+
     out = staleness_stats([a.get("staleness", 0.0) for a in accepted])
     out.update(
         {

@@ -9,13 +9,27 @@ One :class:`Tracer` instance per process. It owns three stores:
 * a bounded **ring buffer** of recent events — in-memory flight recorder for
   tests and debugging, never unbounded.
 
+Besides these, every ``span()`` has a **profiler lane**: it also enters a
+``jax.profiler.TraceAnnotation`` named ``obs.<name>``, enabled tracer or not.
+While a JAX profile is being taken, the span therefore lands on the host plane
+of the profile, on the same clock as the device's operations, so a device
+idle gap can be named by what the host was doing. ``begin``/``end`` pairs stay
+JSONL-only: a dispatch span opens in one call and closes rounds later, maybe on
+another thread, which an annotation (one enclosed block on one thread) cannot
+express.
+
 The disabled path is the contract that lets instrumentation live inside hot
 loops: ``NULL_TRACER`` (and any ``Tracer(enabled=False)``) makes every method
-a constant-time early return that allocates nothing, takes no lock, reads no
-clock and touches no device value — guarded by the overhead test in
+but ``span()`` a constant-time early return that allocates nothing, takes no
+lock, reads no clock and touches no device value. ``span()`` costs the same
+plus its profiler lane, which with no profile being taken is one check of
+whether the profiler is on. Both are guarded by the overhead test in
 ``tests/test_obs.py`` and, more importantly, by the bitwise-parity tests:
 tracing on or off, the aggregation math produces identical bits because the
 tracer only ever *reads* host-side floats the metrics path already computed.
+
+The module imports without JAX: the annotation class is imported on the first
+``span()``, and where JAX is absent the lane is ``contextlib.nullcontext``.
 
 Span identity is caller-supplied and deterministic (see ``obs/events.py``);
 ``begin``/``end`` are split so spans can cross call boundaries (a dispatch
@@ -26,10 +40,26 @@ from __future__ import annotations
 
 import threading
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Callable, Dict, Optional
 
 from .events import Event, JsonlSink, make_event
+
+#: prefix of every span's name on the profiler's host plane
+PROFILER_PREFIX = "obs."
+
+_annotation: Optional[Callable] = None
+
+
+def profiler_lane(name: str):
+    """``jax.profiler.TraceAnnotation(name)``, or a null context without JAX."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation as _annotation
+        except ImportError:
+            _annotation = lambda _name: nullcontext()  # noqa: E731
+    return _annotation(name)
 
 
 class Tracer:
@@ -108,15 +138,17 @@ class Tracer:
         parent: Optional[str] = None,
         **attrs: Any,
     ):
-        """Context-manager form for spans enclosed in one call frame."""
-        if not self.enabled:
-            yield span_id or name
-            return
-        sid = self.begin(name, span_id, parent, **attrs)
-        try:
-            yield sid
-        finally:
-            self.end(sid)
+        """Context-manager form for spans enclosed in one call frame; also the
+        profiler lane's ``obs.<name>`` annotation (see the module docstring)."""
+        with profiler_lane(PROFILER_PREFIX + name):
+            if not self.enabled:
+                yield span_id or name
+                return
+            sid = self.begin(name, span_id, parent, **attrs)
+            try:
+                yield sid
+            finally:
+                self.end(sid)
 
     # -- instants / counters / gauges --------------------------------------
     def point(

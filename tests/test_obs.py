@@ -174,11 +174,14 @@ def test_null_tracer_records_nothing_and_is_cheap():
         NULL_TRACER.point("y", index=i)
         NULL_TRACER.begin("s", span_id="a")
         NULL_TRACER.end("a")
+        with NULL_TRACER.span("s", span_id="a"):
+            pass
     dt = time.perf_counter() - t0
     assert NULL_TRACER.snapshot() == {"counters": {}, "gauges": {}}
     assert len(NULL_TRACER.ring) == 0
-    # generous absolute guard: 400k disabled calls must stay trivially cheap
-    # (no locks, no clocks, no allocation beyond the call itself)
+    # generous absolute guard: 500k disabled calls must stay trivially cheap
+    # (no locks, no clocks, no allocation beyond the call itself; a span's
+    # profiler lane, with no profile being taken, checks whether one is)
     assert dt < 2.0, f"{n} no-op tracer loops took {dt:.2f}s"
 
 
@@ -466,3 +469,160 @@ def test_tracing_leaves_sync_round_bitwise_unchanged(tmp_path):
     assert opened == []
     assert [c["span"] for c in closed] == ["r0", "r1", "r2"]
     assert all("train_loss" in c["attrs"] for c in closed)
+
+
+# ---------------------------------------------------------------------------
+# The profiler lane, the sync loop's span tree, named programs and scopes
+# ---------------------------------------------------------------------------
+
+
+def _host_events(log_dir):
+    """``{name: [(start_s, end_s)]}`` of the ``obs.*`` events on the host planes
+    of the one profile under ``log_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(log_dir / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("obs."):
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns * 1e-9, (ev.start_ns + ev.duration_ns) * 1e-9))
+    return out
+
+
+@pytest.fixture(scope="module")
+def profiled_sync_run(tmp_path_factory):
+    """Two tiny sync rounds of the trainer under a ``jax.profiler`` trace, with
+    the JSONL tracer on; then the scope table of the programs that ran."""
+    from repro.launch.train import parse_args, run
+    from repro.obs import programs
+
+    tmp = tmp_path_factory.mktemp("profiled")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    argv = ["--arch", "photon-75m", "--reduced", "--rounds", "2", "--local-steps", "2",
+            "--clients", "2", "--population", "4", "--batch", "2", "--seq-len", "32",
+            "--eval-batches", "1", "--trace", str(tmp / "server.jsonl")]
+    jax.profiler.start_trace(str(tmp / "profile"), profiler_options=opts)
+    try:
+        out = run(parse_args(argv))
+    finally:
+        jax.profiler.stop_trace()
+    return {"history": out["history"], "host": _host_events(tmp / "profile"),
+            "events": read_events(str(tmp / "server.jsonl")),
+            "scopes": programs.op_scopes()}
+
+
+def test_profiler_lane_nests_the_sync_loop_on_the_host_plane(profiled_sync_run):
+    host = profiled_sync_run["host"]
+    iters = sorted(host["obs.iter"])
+    assert len(iters) == 2
+    for child in ("obs.plan", "obs.data", "obs.h2d", "obs.launch", "obs.sync",
+                  "obs.eval", "obs.log"):
+        spans = host[child]
+        assert len(spans) == 2, child
+        for (s, e), (lo, hi) in zip(sorted(spans), iters):
+            assert lo <= s <= e <= hi, child
+    # no span opens around work a run skips: no controller, guard or checkpoint
+    assert not {"obs.control", "obs.ckpt"} & set(host)
+
+
+def test_iter_span_covers_the_iteration_and_row_seconds_is_its_duration(
+        profiled_sync_run):
+    events = profiled_sync_run["events"]
+    assert span_pairs(events)[1] == []
+    begin = {e.span: e for e in events if e.ph == "B"}
+    end = {e.span: e.mono for e in events if e.ph == "E"}
+    for row in profiled_sync_run["history"]:
+        it = begin[f"i{row['round']}"]
+        assert it.name == "iter" and it.attrs["round"] == row["round"]
+        kids = sorted((b for b in begin.values() if b.parent == it.span),
+                      key=lambda b: b.mono)
+        assert [b.name for b in kids] == [
+            "plan", "data", "h2d", "launch", "sync", "eval", "log"]
+        assert all(it.mono <= b.mono <= end[b.span] <= end[it.span] for b in kids)
+        # the round's own span sits inside launch
+        launch, r = kids[3], begin[f"r{row['round']}"]
+        assert launch.mono <= r.mono <= end[r.span] <= end[launch.span]
+        # `seconds` is the iteration up to its last child, the log: eval included
+        log, ev = kids[-1], kids[-2]
+        assert row["seconds"] == pytest.approx(log.mono - it.mono, abs=1e-3)
+        assert row["seconds"] >= end[ev.span] - it.mono
+
+
+def test_rows_carry_compile_seconds(profiled_sync_run):
+    rows = profiled_sync_run["history"]
+    assert all(r["compile_s"] >= 0.0 for r in rows)
+    assert rows[0]["compiles"] > 0 and rows[0]["compile_s"] > 0.0
+    assert rows[1]["compiles"] == 0 and rows[1]["compile_s"] == 0.0
+
+
+def _parts(table):
+    """Count of the table's op_names in each part of the round program."""
+    parts = {"fwd": 0, "bwd": 0, "opt": 0, "server": 0}
+    for op in table.values():
+        path = op.split(";")[0]
+        if "/server/" in path:
+            parts["server"] += 1
+        elif "/client/" in path:
+            if "transpose(" in path:
+                parts["bwd"] += 1
+            elif "(opt)" in path or "/opt/" in path:
+                parts["opt"] += 1
+            elif "fwd" in path:
+                parts["fwd"] += 1
+    return parts
+
+
+def test_op_scopes_name_the_round_and_eval_programs(profiled_sync_run):
+    scopes = profiled_sync_run["scopes"]
+    assert {"jit_fed_round", "jit_eval_ce"} <= set(scopes)
+    parts = _parts(scopes["jit_fed_round"])
+    assert all(n > 0 for n in parts.values()), parts
+    assert all(op.startswith("jit(eval_ce)/") for op in scopes["jit_eval_ce"].values()
+               if op.startswith("jit("))
+
+
+@pytest.mark.parametrize("tile", [None, 2], ids=["flat", "tiled"])
+def test_op_scopes_split_the_quadratic_round(tile):
+    from repro.obs import programs
+
+    tau, c = 2, 2
+    fed = FederatedConfig(
+        clients_per_round=c, local_steps=tau, inner=sgd_inner(),
+        outer=OuterOptConfig(name="fedadam", lr=0.1),
+    )
+    pcfg = ParticipationConfig(population=4, clients_per_round=c)
+    agg = SyncAggregator(quad_loss, fed, pcfg, seed=0, params=make_params(),
+                         rng=jax.random.PRNGKey(1), cohort_tile=tile)
+    agg.run_round(make_batches(tau, c, seed=5), agg.plan(0))
+    scopes = programs.op_scopes()
+    if tile is None:
+        parts = _parts(scopes["jit_fed_round"])
+    else:
+        parts = _parts(scopes["jit_fed_round_tile"])
+        parts["server"] = _parts(scopes["jit_fed_round_server"])["server"]
+    assert all(n > 0 for n in parts.values()), parts
+
+
+def test_lane_modules_import_without_jax():
+    import subprocess
+    import sys
+
+    code = (
+        "import sys; sys.modules['jax'] = None\n"
+        "from repro.obs import programs, tracer\n"
+        "with tracer.NULL_TRACER.span('x') as s: assert s == 'x'\n"
+        "t = tracer.Tracer()\n"
+        "with t.span('y', span_id='y0') as s: assert s == 'y0'\n"
+        "assert 'jax' not in {m.split('.')[0] for m in sys.modules if sys.modules[m]}\n"
+    )
+    import os
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path))
+    subprocess.run([sys.executable, "-c", code], check=True, env=env)
