@@ -1,4 +1,5 @@
-"""Pure-jnp oracle for the flash attention kernel (GQA, causal, sliding window)."""
+"""Pure-jnp float32 oracle for the flash attention kernels (GQA, causal, sliding
+window, ALiBi)."""
 from __future__ import annotations
 
 from typing import Optional
@@ -17,6 +18,7 @@ def attention_ref(
     causal: bool = True,
     window: Optional[int] = None,
     q_offset: int = 0,  # absolute position of q[0] (decode: Sk - Sq)
+    slopes: Optional[jax.Array] = None,  # (Hq,) ALiBi slopes, or None
 ) -> jax.Array:
     B, Hq, Sq, hd = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
@@ -26,6 +28,9 @@ def attention_ref(
     scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     q_pos = q_offset + jnp.arange(Sq)
     k_pos = jnp.arange(Sk)
+    if slopes is not None:  # -slope_h * (q_pos - k_pos), clipped at 0 as in sdpa_chunked
+        dist = jnp.maximum((q_pos[:, None] - k_pos[None, :]).astype(jnp.float32), 0.0)
+        scores = scores - slopes.reshape(Hkv, grp, 1, 1).astype(jnp.float32) * dist
     mask = jnp.ones((Sq, Sk), bool)
     if causal:
         mask &= k_pos[None, :] <= q_pos[:, None]

@@ -1,6 +1,7 @@
 """Grouped-query attention with qk-norm, RoPE/ALiBi/learned positions, sliding windows,
-cross-attention, and KV-cache decode. The scaled-dot-product core dispatches to the
-Pallas flash kernel on TPU (cfg-controlled) and the pure-jnp reference otherwise.
+cross-attention, and KV-cache decode. The scaled-dot-product core runs in the Pallas
+flash kernels (forward and backward) on TPU wherever the call allows it
+(:func:`flash_eligible`), and in the pure-jnp paths otherwise.
 """
 from __future__ import annotations
 
@@ -160,6 +161,31 @@ def sdpa_chunked(
     return jnp.moveaxis(out, 0, 1).reshape(B, Sq, Hq, hd)
 
 
+def flash_eligible(
+    platform: str,
+    *,
+    self_attention: bool,
+    decode: bool,
+    window,
+    sq: int,
+    sk: int,
+    hd: int,
+) -> bool:
+    """Whether the scaled-dot-product runs in the Pallas flash kernels: on the
+    TPU, for self-attention outside decode (no cache length to mask), with no
+    window or a static one, at shapes the kernels tile. ALiBi and RoPE both
+    qualify; everything else keeps the jnp paths."""
+    from repro.kernels.flash_attention import ops as fa_ops
+
+    return (
+        platform == "tpu"
+        and self_attention
+        and not decode
+        and (window is None or isinstance(window, int))
+        and fa_ops.pick_blocks(sq, sk, hd) is not None
+    )
+
+
 # ---------------------------------------------------------------------------
 # Full attention layer
 # ---------------------------------------------------------------------------
@@ -176,7 +202,6 @@ def attention(
     cache: Optional[dict] = None,  # {'k': (B, Smax, Hkv, hd), 'v': ..., } decode/prefill
     cache_index: Optional[jax.Array] = None,  # scalar write offset for decode
     kv_source: Optional[jax.Array] = None,  # cross-attention memory (B, Skv, D)
-    use_pallas: bool = False,
 ) -> Tuple[jax.Array, Optional[dict]]:
     B, S, D = x.shape
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
@@ -226,32 +251,30 @@ def attention(
         if cfg.pos_embedding == "alibi":
             slopes = alibi_slopes(cfg.n_heads)  # (Hq,)
 
-    if (
-        use_pallas
-        and slopes is None
-        and kv_source is None
-        and k_len is None
-        and (window is None or isinstance(window, int))
-    ):
-        from repro.kernels.flash_attention import ops as fa_ops
+    with jax.named_scope("attn"):  # the core, for the trace (bench/attn_parts.py)
+        if flash_eligible(
+            jax.default_backend(), self_attention=kv_source is None,
+            decode=cache_index is not None, window=eff_window, sq=S, sk=Sk, hd=q.shape[-1],
+        ):
+            from repro.kernels.flash_attention import ops as fa_ops
 
-        out = fa_ops.flash_attention(q, k, v, causal=causal, window=window)
-    elif S >= 512:
-        out = sdpa_chunked(
-            q, k, v, q_pos=positions, k_pos=k_positions, causal=eff_causal,
-            window=eff_window, k_len=k_len, slopes=slopes,
-        )
-    else:
-        mask = (
-            None
-            if kv_source is not None
-            else make_mask(positions, k_positions, eff_causal, eff_window, k_len)
-        )
-        bias = None
-        if slopes is not None:
-            dist = (positions[:, None] - k_positions[None, :]).astype(jnp.float32)
-            bias = (-slopes[:, None, None] * jnp.maximum(dist, 0.0))[None]
-        out = sdpa(q, k, v, mask, bias)
+            out = fa_ops.flash_attention(q, k, v, slopes, causal=eff_causal, window=eff_window)
+        elif S >= 512:
+            out = sdpa_chunked(
+                q, k, v, q_pos=positions, k_pos=k_positions, causal=eff_causal,
+                window=eff_window, k_len=k_len, slopes=slopes,
+            )
+        else:
+            mask = (
+                None
+                if kv_source is not None
+                else make_mask(positions, k_positions, eff_causal, eff_window, k_len)
+            )
+            bias = None
+            if slopes is not None:
+                dist = (positions[:, None] - k_positions[None, :]).astype(jnp.float32)
+                bias = (-slopes[:, None, None] * jnp.maximum(dist, 0.0))[None]
+            out = sdpa(q, k, v, mask, bias)
 
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return y, new_cache

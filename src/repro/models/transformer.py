@@ -216,7 +216,6 @@ def _apply_layer(
             window=window,
             cache=cache.get("mixer") if cache else None,
             cache_index=cache_index,
-            use_pallas=use_pallas,
         )
     else:
         a, mc = ssm_mod.ssm_block(
@@ -284,13 +283,19 @@ def _apply_segment(
     windows = seg.window_array(all_kinds)  # (n_repeat, period)
 
     def make_layer_fn(pidx, kind):
+        # a window every repeat shares is passed as a python value (None or int),
+        # which the attention kernels take; only windows that differ between
+        # repeats are the scanned data
+        shared = {all_kinds[seg.first_layer + r * seg.period + pidx].window
+                  for r in range(seg.n_repeat)}
+
         def layer_fn(h, params_l, window_l, cache_l):
             return _apply_layer(
                 cfg,
                 kind,
                 params_l,
                 h,
-                window=window_l,
+                window=next(iter(shared)) if len(shared) == 1 else window_l,
                 positions=positions,
                 cache=cache_l,
                 cache_index=cache_index,
@@ -358,7 +363,7 @@ def _apply_segment(
 # ---------------------------------------------------------------------------
 
 
-def _encode(cfg: ModelConfig, enc_params: dict, audio_embed: jax.Array, use_pallas: bool):
+def _encode(cfg: ModelConfig, enc_params: dict, audio_embed: jax.Array):
     h = audio_embed + enc_params["audio_pos"][None, : audio_embed.shape[1]].astype(audio_embed.dtype)
     kinds = cfg.encoder_layer_kinds()
     segs = plan_segments(kinds)
@@ -371,7 +376,6 @@ def _encode(cfg: ModelConfig, enc_params: dict, audio_embed: jax.Array, use_pall
             x = apply_norm(cfg, params_r["pos0"]["norm1"], h)
             a, _ = attn_mod.attention(
                 cfg, params_r["pos0"]["mixer"], x, positions=positions, causal=False,
-                use_pallas=use_pallas,
             )
             h = h + a
             x2 = apply_norm(cfg, params_r["pos0"]["norm2"], h)
@@ -429,7 +433,7 @@ def forward(
     enc_out = None
     if cfg.enc_dec and not decode:
         assert audio_embed is not None, "enc-dec model requires audio_embed"
-        enc_out = _encode(cfg, params["encoder"], audio_embed.astype(compute_dtype), use_pallas)
+        enc_out = _encode(cfg, params["encoder"], audio_embed.astype(compute_dtype))
 
     all_kinds = cfg.layer_kinds()
     segs = plan_segments(all_kinds)

@@ -69,6 +69,79 @@ def test_flash_attention_q_offset_decode_tail():
     )
 
 
+def _alibi(h):
+    from repro.models.common import alibi_slopes
+
+    return alibi_slopes(h)
+
+
+def _ref_bshd(q, k, v, causal, slopes, window=None):
+    """The float32 oracle in the model's (B, S, H, hd) layout."""
+    t = lambda x: jnp.swapaxes(x.astype(jnp.float32), 1, 2)  # noqa: E731
+    out = attention_ref(t(q), t(k), t(v), causal=causal, window=window, slopes=slopes)
+    return jnp.swapaxes(out, 1, 2)
+
+
+@pytest.mark.parametrize(
+    "B,Hq,Hkv,S,hd,causal,window,alibi,dtype,clients",
+    [
+        (1, 2, 2, 128, 64, True, None, True, jnp.float32, 0),  # MHA, ALiBi
+        (1, 4, 2, 128, 64, True, None, False, jnp.float32, 0),  # GQA
+        (2, 2, 2, 128, 64, False, None, True, jnp.float32, 0),  # bidirectional ALiBi
+        (1, 2, 2, 256, 64, True, 96, True, jnp.float32, 0),  # static sliding window
+        (1, 2, 1, 256, 128, True, None, True, jnp.bfloat16, 0),  # hd 128, MQA
+        (1, 2, 2, 256, 64, True, None, False, jnp.bfloat16, 0),
+        (1, 2, 2, 128, 64, True, None, True, jnp.bfloat16, 2),  # vmapped clients, as the round
+    ],
+)
+def test_flash_attention_grads_match_ref(B, Hq, Hkv, S, hd, causal, window, alibi, dtype,
+                                         clients):
+    """Forward output and dQ, dK, dV of the kernels (interpret mode) against the
+    float32 oracle's, over several blocks (64 in interpret mode): skipped,
+    masked in strips, and whole."""
+    lead = (clients,) if clients else ()
+    ks = jax.random.split(jax.random.PRNGKey(S + hd + Hkv), 4)
+    q = _rand(ks[0], lead + (B, S, Hq, hd), dtype)
+    k = _rand(ks[1], lead + (B, S, Hkv, hd), dtype)
+    v = _rand(ks[2], lead + (B, S, Hkv, hd), dtype)
+    do = _rand(ks[3], lead + (B, S, Hq, hd), jnp.float32)
+    slopes = _alibi(Hq) if alibi else None
+
+    def kern(q, k, v):
+        return flash_attention(q, k, v, slopes, causal=causal, window=window, interpret=True)
+
+    def ref(q, k, v):
+        return _ref_bshd(q, k, v, causal, slopes, window)
+
+    if clients:
+        kern, ref = jax.vmap(kern), jax.vmap(ref)
+
+    def vjp(fn):
+        out, back = jax.vjp(fn, q, k, v)
+        return (out,) + back(do.astype(out.dtype))
+
+    tol = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}[dtype]
+    for name, got, want in zip(("out", "dq", "dk", "dv"), vjp(kern), vjp(ref)):
+        want = np.asarray(want, np.float32)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), want, rtol=tol,
+            atol=tol * float(np.abs(want).max()), err_msg=name)
+
+
+def test_flash_attention_alibi_matches_chunked_path():
+    """The kernels' ALiBi bias equals sdpa_chunked's (scale, bias, mask), in float32."""
+    from repro.models.attention import sdpa_chunked
+
+    B, H, S, hd = 1, 2, 512, 32
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q, k, v = (_rand(kk, (B, S, H, hd), jnp.float32) for kk in ks)
+    pos = jnp.arange(S)
+    want = sdpa_chunked(q, k, v, q_pos=pos, k_pos=pos, causal=True, window=None,
+                        k_len=None, slopes=_alibi(H))
+    got = flash_attention(q, k, v, _alibi(H), causal=True, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # flash_decode
 # ---------------------------------------------------------------------------

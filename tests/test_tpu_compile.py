@@ -1,11 +1,13 @@
-"""Real-width TPU v5e compiles of the fedcore kernels (kernels/fedcore/kernel.py).
+"""Real-width TPU v5e compiles of the fedcore kernels (kernels/fedcore/kernel.py)
+and of the flash attention kernels (kernels/flash_attention/).
 
-The interpret-mode parity tests (tests/test_fed_kernels.py) run the kernels'
-Python bodies on the CPU, which accepts programs the TPU compiler refuses
-(scalar stores into VMEM, misaligned blocks, VMEM overflows). Here each kernel
-is compiled, not run, for one chip of a described ``v5e:2x2`` topology at
-photon-125m's packed flat size, and the compiled module must hold the Mosaic
-kernel (``tpu_custom_call``).
+The interpret-mode parity tests (tests/test_fed_kernels.py, tests/test_kernels.py)
+run the kernels' Python bodies on the CPU, which accepts programs the TPU
+compiler refuses (scalar stores into VMEM, misaligned blocks, VMEM overflows).
+Here each kernel is compiled, not run, for one chip of a described ``v5e:2x2``
+topology, the fedcore kernels at photon-125m's packed flat size and the
+attention kernels at the benchmark cells' shapes, and the compiled module must
+hold the Mosaic kernel (``tpu_custom_call``).
 
 The topology is described inside a module-scoped fixture and nowhere else:
 only one process may load the TPU library, so describing it while a module is
@@ -20,6 +22,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.fedcore import kernel as K
 from repro.kernels.fedcore.ops import BLOCK
+from repro.kernels.flash_attention import flash_attention
+from repro.models.common import alibi_slopes
 
 # photon-125m: 123,704,832 float32 params, packed and padded to a BLOCK multiple
 N_PARAMS = 123_704_832
@@ -88,3 +92,20 @@ def test_server_apply_compiles_for_v5e(one_chip, opt):
 def test_codec_kernel_compiles_for_v5e(one_chip, name, fn, shapes):
     compiled = _compile(fn, one_chip, *shapes)
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+@pytest.mark.parametrize("B,H,S,hd", [(2, 12, 2048, 64), (1, 16, 2048, 128)])
+def test_flash_attention_fwd_bwd_compile_for_v5e(one_chip, B, H, S, hd):
+    """photon-125m's and photon-1.3b's causal ALiBi attention, forward and
+    backward, as a client step of the benchmark cells runs it."""
+
+    def fwd_bwd(q, k, v, do):
+        f = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, alibi_slopes(H), causal=True, interpret=False)
+        out, vjp = jax.vjp(f, q, k, v)
+        return out, vjp(do)
+
+    compiled = _compile(fwd_bwd, one_chip, *[((B, S, H, hd), jnp.bfloat16)] * 4)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "flash_fwd" in text and "flash_bwd" in text
