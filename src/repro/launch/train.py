@@ -782,8 +782,10 @@ def _run_sync_rounds(args, model, agg, streams, val_stream, ckpt, logger,
     guarded = agg.robust_state is not None and agg.robust is not None and agg.robust.rollback
     for rnd in range(start_round, args.rounds):
         it = f"i{rnd}"
+        # monotonic: durations, never wall timestamps; read before the span's
+        # begin, so that the row's seconds cover every child span whole
+        t0 = time.perf_counter()
         with span("iter", it, round=rnd):
-            t0 = time.perf_counter()  # monotonic: durations, never wall timestamps
             c0, s0 = compiles.count, compiles.seconds
             with span("plan", f"{it}/plan", it):
                 plan = agg.plan(rnd)
