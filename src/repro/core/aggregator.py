@@ -61,14 +61,8 @@ from repro.core.compression import Codec
 from repro.core.federated import (
     FederatedConfig,
     SparseResidualStore,
-    _finish_aggregate,
-    _weigh_clients,
-    apply_aggregate_partial,
     combine_tile_metrics,
-    federated_round,
-    federated_round_with_uplink,
     init_federated_state,
-    run_client_tile,
     run_clients,
     tile_rng,
     trace_attrs,
@@ -78,12 +72,13 @@ from repro.core.robust import (
     RobustAggConfig,
     RobustState,
     make_robust_apply_fn,
-    normclip_scale,
-    sanitize_deltas,
-    tile_fold_finish,
     tile_fold_init,
     tile_fold_size,
-    tile_fold_update,
+)
+from repro.core.round_program import (
+    RoundPrograms,
+    build_round_programs,
+    check_round_options,
 )
 from repro.obs import programs
 from repro.obs.metrics import observe_staleness
@@ -277,26 +272,6 @@ class SyncAggregator(Aggregator):
     ):
         self.tracer = get_tracer(tracer)
         self.controller = controller
-        if robust is not None and robust.active and fused_server:
-            raise ValueError(
-                "--fused-server is a plain weighted-mean flat-buffer pass and "
-                "cannot host a robust rule or the delta screen — drop one of "
-                "--fused-server / --robust-agg / --screen"
-            )
-        if robust is not None and cohort_tile is not None:
-            if robust.screen:
-                raise ValueError(
-                    "the median/MAD delta screen needs the whole cohort's "
-                    "norms in one pass and cannot compose with --cohort-tile "
-                    "(tiles fold before the cohort median exists) — drop "
-                    "--screen or --cohort-tile"
-                )
-            if robust.rule == "normclip" and robust.clip_norm <= 0.0:
-                raise ValueError(
-                    "adaptive norm-clipping (clip_norm=0) needs the cohort "
-                    "median norm before any tile folds — use an absolute "
-                    "--clip-norm with --cohort-tile"
-                )
         self.robust = robust
         self.robust_state = (
             RobustState(robust) if robust is not None and robust.stateful else None
@@ -310,161 +285,38 @@ class SyncAggregator(Aggregator):
         self.codec = codec
         self.seed = seed
         self.fused_server = fused_server
-        if cohort_tile is not None:
-            cohort_tile = int(cohort_tile)
-            if cohort_tile < 1:
-                raise ValueError(f"cohort_tile must be >= 1, got {cohort_tile}")
-            if fed.keep_inner_state:
-                raise ValueError(
-                    "cohort tiling cannot keep per-client inner state across "
-                    "rounds (the (K, ...)-shaped inner store is the memory "
-                    "term tiling removes) — drop --keep-opt or --cohort-tile"
-                )
-            if fused_server:
-                raise ValueError(
-                    "--fused-server consumes the full (C, N) delta buffer with "
-                    "pre-normalized weights, not the tiled partial-sum layout "
-                    "— drop one of --fused-server / --cohort-tile"
-                )
-        self.cohort_tile = cohort_tile
+        self.cohort_tile = None if cohort_tile is None else int(cohort_tile)
         self.donate = donate
+        self._loss_fn = loss_fn
+        self._shard_clients = shard_clients
+        self._build_round_fn()
         self.residual_store = SparseResidualStore.create(
             codec, params if params is not None else (state or {}).get("params")
         )
-        apply_fn = None
-        if fused_server:
-            # deferred: kernels/fedcore imports core modules for the seam types
-            from repro.kernels.fedcore import fused_apply_aggregate
-
-            apply_fn = fused_apply_aggregate
-        elif robust is not None and robust.active and cohort_tile is None:
-            # the robust server phase is a drop-in at the same apply_fn seam
-            # the fused phase uses; the tiled path composes differently (a
-            # per-tile order-statistic fold, built in _build_round_fn)
-            apply_fn = make_robust_apply_fn(fed, robust)
-        self._loss_fn = loss_fn
-        self._shard_clients = shard_clients
-        self._apply_fn = apply_fn
         if state is None:
             state = init_federated_state(fed, params, rng)
             # take ownership: the round jit donates the state (see _own)
             self.state = _own(state) if donate else state
         else:
             self.restore(state, None)
-        self._build_round_fn()
 
     def _build_round_fn(self) -> None:
-        """(Re)build the jitted round from the CURRENT ``self.fed``/codec.
+        """(Re)build the round programs from the CURRENT ``self.fed``/codec
+        (:func:`~repro.core.round_program.build_round_programs`, which also
+        refuses incompatible options).
 
         Called at construction and again by :meth:`apply_knobs` when the
         cohort-size knob changes: the round jit closes over ``fed`` (the
         cohort broadcast width), so a new K needs a fresh closure — XLA then
-        retraces once at the new bucketed cohort shape.
-
-        Flat path: one jit per round — ``(state, batches, weights[, residuals]
-        [, tau])``. The cohort's error-feedback rows enter as a traced argument
-        (the host gathers them from the sparse store), NOT via an in-state
-        ``(P, ...)`` array, so the jitted computation never sees the
-        population. Tiled path (``cohort_tile``): a tile jit replayed per
-        C_tile slice plus the partial-sum server jit."""
-        loss_fn, fed, codec = self._loss_fn, self.fed, self.codec
-        shard_clients, apply_fn = self._shard_clients, self._apply_fn
-        stateful = codec is not None and codec.stateful
-        # the aggregator exclusively owns its state pytree (params, outer
-        # lanes, rng — and the inner states under keep_inner_state), and every
-        # round replaces it wholesale: donating it lets XLA update the
-        # params-sized lanes in place instead of double-buffering them (a no-op
-        # on backends without donation support). The gathered residual rows are
-        # freshly stacked per round and replaced by the round's output rows, so
-        # they donate too.
-        if self.cohort_tile is not None:
-            fed_tile = replace(fed, clients_per_round=self.cohort_tile)
-            donate_kw = {"donate_argnums": (3,)} if self.donate else {}
-            robust = self.robust
-            robust_tiled = robust is not None and robust.active
-            # the robust fold needs the tile's decoded per-client deltas (order
-            # statistics cannot be recovered from the weighted partial sum);
-            # the default path keeps the memory-minimal partial-sum-only output
-            return_deltas = robust_tiled
-
-            def fed_round_tile(s, b, w, res, tau):
-                return run_client_tile(
-                    loss_fn, fed_tile, s, b, w, shard_clients=shard_clients,
-                    codec=codec, residuals=res, tau_steps=tau,
-                    return_deltas=return_deltas,
-                )
-
-            def fed_round_server(s, dsum, w, dn):
-                with jax.named_scope("server"):
-                    return apply_aggregate_partial(fed, s, dsum, w, dn)
-
-            self._tile_fn = programs.register(jax.jit(fed_round_tile, **donate_kw))
-            # donate the server state only: the Σ wΔ partial sums feed the
-            # pseudo-gradient metrics as well as the update, so XLA cannot
-            # alias their buffers (donating them would just warn)
-            self._apply_partial_fn = programs.register(jax.jit(
-                fed_round_server,
-                **({"donate_argnums": (0,)} if self.donate else {}),
-            ))
-            self._fold_update_fn = self._fold_finish_fn = None
-            self._tile_clip_fn = None
-            if robust_tiled and robust.rule in ("trimmed", "median"):
-                rule, trim = robust.rule, robust.trim_fraction
-
-                def fed_round_fold(fold, deltas, norms, w):
-                    admit = (w > 0) & jnp.isfinite(norms)
-                    return tile_fold_update(
-                        fold, sanitize_deltas(deltas, jnp.isfinite(norms)), admit
-                    )
-
-                def fed_round_fold_server(fold, s, dn, w):
-                    with jax.named_scope("server"):
-                        pg = tile_fold_finish(fold, rule, trim)
-                        return _finish_aggregate(fed, s, pg, dn, w)
-
-                self._fold_update_fn = programs.register(jax.jit(
-                    fed_round_fold,
-                    **({"donate_argnums": (0,)} if self.donate else {}),
-                ))
-                self._fold_finish_fn = programs.register(jax.jit(
-                    fed_round_fold_server,
-                    **({"donate_argnums": (1,)} if self.donate else {}),
-                ))
-            elif robust_tiled and robust.rule == "normclip":
-                tau_clip = float(robust.clip_norm)  # absolute-only with tiles
-
-                def fed_round_tile_clip(deltas, norms, w):
-                    admit = (w > 0) & jnp.isfinite(norms)
-                    scale = normclip_scale(
-                        norms, admit, jnp.asarray(tau_clip, jnp.float32)
-                    )
-                    clean = sanitize_deltas(deltas, jnp.isfinite(norms))
-                    return jax.tree_util.tree_map(
-                        lambda x: jnp.sum(
-                            _weigh_clients(x, w.astype(jnp.float32) * scale),
-                            axis=0,
-                        ),
-                        clean,
-                    )
-
-                self._tile_clip_fn = programs.register(jax.jit(fed_round_tile_clip))
-            self._round_fn = None
-            return
-        self._tile_fn = self._apply_partial_fn = None
-        self._fold_update_fn = self._fold_finish_fn = self._tile_clip_fn = None
-        # one round program: the residual rows are None without a stateful
-        # codec, and the τ-vector is all-full without partial progress
-        donate = (0, 3) if stateful else (0,)
-        donate_kw = {"donate_argnums": donate} if self.donate else {}
-
-        def fed_round(s, b, w, res, tau):
-            return federated_round(
-                loss_fn, fed, s, b, client_weights=w, codec=codec,
-                residuals=res, shard_clients=shard_clients, tau_steps=tau,
-                apply_fn=apply_fn,
-            )
-
-        self._round_fn = programs.register(jax.jit(fed_round, **donate_kw))
+        retraces once at the new bucketed cohort shape."""
+        built = build_round_programs(
+            self._loss_fn, self.fed, codec=self.codec, robust=self.robust,
+            fused_server=self.fused_server, cohort_tile=self.cohort_tile,
+            shard_clients=self._shard_clients, donate=self.donate,
+        )
+        self._progs = RoundPrograms(
+            *(None if p is None else programs.register(p) for p in built)
+        )
 
     def apply_knobs(self, update) -> None:
         """Apply a sync :class:`KnobUpdate` between rounds.
@@ -577,7 +429,7 @@ class SyncAggregator(Aggregator):
         take/set — the gathered values are identical)."""
         stateful = self.residual_store is not None
         res = self.residual_store.gather(plan.selected) if stateful else None
-        self.state, metrics = self._round_fn(
+        self.state, metrics = self._progs.round(
             self.state, batches, w, res, jnp.asarray(self.tau_steps(plan))
         )
         if stateful:
@@ -604,6 +456,7 @@ class SyncAggregator(Aggregator):
         ct = self.cohort_tile
         n_tiles = -(-C // ct)
         stateful = self.residual_store is not None
+        progs = self._progs
         w_np = np.asarray(w, np.float32)
         tau_np = self.tau_steps(plan)
         w_full = np.zeros(n_tiles * ct, np.float32)
@@ -614,7 +467,7 @@ class SyncAggregator(Aggregator):
         delta_norms = []
         tile_outs = []
         fold = None
-        if self._fold_update_fn is not None:
+        if progs.fold is not None:
             k = tile_fold_size(
                 self.robust.rule, self.robust.trim_fraction, n_tiles * ct
             )
@@ -651,7 +504,7 @@ class SyncAggregator(Aggregator):
                 )
             )
             s_t = dict(core, rng=tile_rng(base_rng, t_idx))
-            out = self._tile_fn(s_t, b_t, w_t, res_t, tau_t)
+            out = progs.tile(s_t, b_t, w_t, res_t, tau_t)
             if stateful:
                 rows = out.pop("residuals")
                 self.residual_store.scatter(
@@ -660,14 +513,14 @@ class SyncAggregator(Aggregator):
                 )
             ds = out.pop("delta_sum")
             dn_t = out.pop("delta_norms")
-            if self._fold_update_fn is not None:
+            if progs.fold is not None:
                 # robust tiled (trimmed/median): fold per-tile order-statistic
                 # moments instead of the weighted partial sum
-                fold = self._fold_update_fn(fold, out.pop("deltas"), dn_t, w_t)
-            elif self._tile_clip_fn is not None:
+                fold = progs.fold(fold, out.pop("deltas"), dn_t, w_t)
+            elif progs.tile_clip is not None:
                 # robust tiled normclip: clip each client within its tile at
                 # the absolute τ, then the standard Σ wΔ accumulation
-                ds = self._tile_clip_fn(out.pop("deltas"), dn_t, w_t)
+                ds = progs.tile_clip(out.pop("deltas"), dn_t, w_t)
                 delta_sum = ds if delta_sum is None else jax.tree_util.tree_map(
                     jnp.add, delta_sum, ds
                 )
@@ -677,13 +530,13 @@ class SyncAggregator(Aggregator):
                 )
             delta_norms.append(dn_t)
             tile_outs.append(out)
-        if self._fold_update_fn is not None:
-            new_state, agg_metrics = self._fold_finish_fn(
+        if progs.fold is not None:
+            new_state, agg_metrics = progs.fold_server(
                 fold, self.state, jnp.concatenate(delta_norms),
                 jnp.asarray(w_full),
             )
         else:
-            new_state, agg_metrics = self._apply_partial_fn(
+            new_state, agg_metrics = progs.server(
                 self.state, delta_sum, jnp.asarray(w_full),
                 jnp.concatenate(delta_norms),
             )
@@ -851,12 +704,7 @@ class AsyncBufferAggregator(Aggregator):
         self.fused_server = fused_server
         self.tracer = get_tracer(tracer)
         self.controller = controller
-        if robust is not None and robust.active and fused_server:
-            raise ValueError(
-                "--fused-server is a plain weighted-mean flat-buffer pass and "
-                "cannot host a robust rule or the delta screen — drop one of "
-                "--fused-server / --robust-agg / --screen"
-            )
+        check_round_options(fed, robust=robust, fused_server=fused_server)
         self.robust = robust
         self.robust_state = (
             RobustState(robust) if robust is not None and robust.stateful else None

@@ -28,9 +28,6 @@ def main() -> None:
     ap.add_argument("--tau-lowered", type=int, default=4)
     ap.add_argument("--train-mode", default="federated", choices=["federated", "centralized", "both"])
     ap.add_argument("--no-remat", action="store_true")
-    ap.add_argument("--no-elastic", action="store_true",
-                    help="drop the (C,) participation-weight input from the "
-                         "federated round (legacy flat-mean lowering)")
     ap.add_argument("--pseudo-grad-dtype", default="float32")
     ap.add_argument("--uplink", default="float32",
                     choices=list(UPLINK_SCHEMES),
@@ -38,16 +35,6 @@ def main() -> None:
                          "encoded-delta dtypes are carried through the mesh "
                          "lowering (residual inputs sharded like the client axis)")
     ap.add_argument("--topk-fraction", type=float, default=0.05)
-    ap.add_argument("--partial-progress", action="store_true",
-                    help="thread the (C,) straggler partial-progress τ-mask "
-                         "through the federated round (replicated int32 input "
-                         "consumed inside the scan — shardings unperturbed)")
-    ap.add_argument("--fused-server", action="store_true",
-                    help="request the fused flat-buffer server phase "
-                         "(kernels/fedcore). On multi-device meshes the GSPMD "
-                         "lowering keeps the reference phase (the fused kernel "
-                         "is the aggregator-host path), so this asserts the "
-                         "flag cannot perturb shardings or footprint")
     ap.add_argument("--cohort-tile", type=int, default=None,
                     help="lower the federated step as ONE TILE of a streamed "
                          "cohort (run_client_tile, client width = tile): the "
@@ -105,11 +92,8 @@ def main() -> None:
                                 remat=not args.no_remat,
                                 mode=mode,
                                 pseudo_grad_dtype=args.pseudo_grad_dtype,
-                                elastic=not args.no_elastic,
                                 uplink=args.uplink,
                                 topk_fraction=args.topk_fraction,
-                                partial_progress=args.partial_progress,
-                                fused_server=args.fused_server,
                                 cohort_tile=args.cohort_tile,
                             )
                         with mesh:

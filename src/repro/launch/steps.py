@@ -8,7 +8,7 @@ For every (architecture × input shape × mesh) this module produces:
 from __future__ import annotations
 
 import functools
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -22,12 +22,11 @@ from repro.core import (
     InnerOptConfig,
     OuterOptConfig,
     centralized_step,
-    federated_round,
     get_codec,
     init_uplink_residuals,
-    run_client_tile,
 )
 from repro.core.outer_opt import init_outer_state
+from repro.core.round_program import build_round_programs
 from repro.models import build_model
 from repro.sharding import specs as sh
 
@@ -111,9 +110,7 @@ def input_specs(
     if shape.kind == "train":
         client_ax, fsdp_ax, C = sh.choose_client_mapping(mesh, cfg.param_count())
         b_loc = shape.global_batch // C
-        import numpy as _np
-
-        fsdp_div = int(_np.prod([mesh.shape[a] for a in fsdp_ax])) if fsdp_ax else 1
+        fsdp_div = int(np.prod([mesh.shape[a] for a in fsdp_ax])) if fsdp_ax else 1
         ga = default_grad_accum(b_loc, shape.seq_len, fsdp_div,
                                 target_tokens=_target_tokens(cfg))
         b_mb = b_loc // ga
@@ -251,87 +248,45 @@ def build_train_step(
     mode: str = "federated",
     fed: Optional[FederatedConfig] = None,
     pseudo_grad_dtype: str = "float32",
-    elastic: bool = True,
     uplink: str = "float32",
     topk_fraction: float = 0.05,
-    partial_progress: bool = False,
-    fused_server: bool = False,
     cohort_tile: Optional[int] = None,
 ) -> BuiltStep:
     model = build_model(cfg)
     loss_fn = lambda p, b: model.loss(p, b, remat=remat)
 
     if mode == "federated":
+        # the trainer's round (core/round_program), lowered on the mesh: this
+        # branch only maps clients onto the mesh and builds the abstract
+        # arguments in the trainer's signature
         client_ax, fsdp_ax, C = sh.choose_client_mapping(mesh, cfg.param_count())
-        b_loc = shape.global_batch // C
-        import numpy as _np
-
-        fsdp_div = int(_np.prod([mesh.shape[a] for a in fsdp_ax])) if fsdp_ax else 1
-        ga = default_grad_accum(b_loc, shape.seq_len, fsdp_div,
+        fsdp_div = int(np.prod([mesh.shape[a] for a in fsdp_ax])) if fsdp_ax else 1
+        ga = default_grad_accum(shape.global_batch // C, shape.seq_len, fsdp_div,
                                 target_tokens=_target_tokens(cfg))
-        fed = fed or default_fed_config(C, tau_lowered, ga)
-        from dataclasses import replace
-
-        fed = replace(fed, pre_split_micro=True)
+        fed = replace(fed or default_fed_config(C, tau_lowered, ga),
+                      grad_accum=ga, pre_split_micro=True)
         if pseudo_grad_dtype != "float32":
             fed = replace(fed, pseudo_grad_dtype=pseudo_grad_dtype)
         state, pspecs = abstract_fed_state(model, mesh, fed, fsdp_ax)
         client_pspecs = sh.clientize_tree(mesh, pspecs, client_ax)
+        client_shardings = jax.tree_util.tree_map(
+            lambda p: NamedSharding(mesh, p), client_pspecs,
+            is_leaf=lambda x: isinstance(x, P),
+        )
 
         def shard_clients(tree):
-            return jax.lax.with_sharding_constraint(
-                tree,
-                jax.tree_util.tree_map(
-                    lambda p: NamedSharding(mesh, p), client_pspecs,
-                    is_leaf=lambda x: isinstance(x, P),
-                ),
-            )
+            return jax.lax.with_sharding_constraint(tree, client_shardings)
 
-        # the fused flat-buffer server phase (kernels/fedcore) is the
-        # aggregator-host path: it consumes the whole (C, N) delta buffer in one
-        # kernel, which cannot span a GSPMD-sharded client axis. On multi-device
-        # meshes the flag therefore keeps the reference server phase — by
-        # construction the lowering, shardings and memory footprint are
-        # identical with or without --fused-server (the dry-run smoke asserts
-        # it); only single-device lowerings swap the fused pass in.
-        fused_active = fused_server and mesh.size == 1
-        codec = (
-            get_codec(uplink, topk_fraction, fused=fused_active)
-            if uplink != "float32" else None
-        )
-        stateful = codec is not None and codec.stateful
-        if (stateful or partial_progress) and not elastic:
-            raise ValueError(
-                "stateful uplink codecs and partial progress require the "
-                "elastic round"
-            )
-        apply_fn = None
-        if fused_active:
-            from repro.kernels.fedcore import fused_apply_aggregate
-
-            apply_fn = fused_apply_aggregate
+        codec = get_codec(uplink, topk_fraction) if uplink != "float32" else None
         batches = input_specs(cfg, shape, mesh, tau_lowered=tau_lowered, mode="federated")
-
+        width = C
         if cohort_tile is not None:
             # streamed-cohort lowering: the compiled unit is ONE TILE of the
-            # round (run_client_tile), client width = cohort_tile. The host
-            # loop replays it over every tile and folds the weighted partial
-            # sums (docs/aggregation.md), so per-device memory is bounded by
-            # the tile — the population P and the cohort C never enter the
-            # lowering at all. The tile's client dim shards over the same
-            # client axes as the flat round.
-            if not elastic:
-                raise ValueError("cohort tiling requires the elastic round: "
-                                 "pad slots ride as zero-weight clients")
-            if fused_server:
-                raise ValueError(
-                    "--fused-server consumes the full (C, N) delta buffer "
-                    "with pre-normalized weights, not the tiled partial-sum "
-                    "layout"
-                )
-            client_width = int(
-                _np.prod([mesh.shape[a] for a in client_ax])
-            ) if client_ax else 1
+            # round; the host loop replays it over every tile and folds the
+            # weighted partial sums (docs/aggregation.md), so the population
+            # and the cohort never enter the lowering. The tile's client dim
+            # shards over the same client axes as the flat round.
+            client_width = int(np.prod([mesh.shape[a] for a in client_ax])) if client_ax else 1
             if cohort_tile % client_width:
                 raise ValueError(
                     f"cohort_tile={cohort_tile} must be a multiple of the "
@@ -339,134 +294,54 @@ def build_train_step(
                     f"{list(client_ax)}): jit inputs reject uneven GSPMD "
                     f"padding on the sharded client dim"
                 )
-            fed_tile = replace(fed, clients_per_round=cohort_tile)
-
-            def _retile(sds):
-                return jax.ShapeDtypeStruct(
-                    (sds.shape[0], cohort_tile) + sds.shape[2:],
-                    sds.dtype, sharding=sds.sharding,
-                )
-
+            width = cohort_tile
             batches = jax.tree_util.tree_map(
-                _retile, batches,
+                lambda x: jax.ShapeDtypeStruct(
+                    (x.shape[0], width) + x.shape[2:], x.dtype, sharding=x.sharding
+                ),
+                batches,
                 is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct),
             )
-            # run_client_tile reads only the params/round/rng lanes; the outer
-            # optimizer state stays host-side with apply_aggregate_partial
-            tile_state = {k: state[k] for k in ("params", "round", "rng")}
-            args = (tile_state, batches,
-                    _sds((cohort_tile,), jnp.float32, mesh, P()))
-            arg_names = ["client_weights"]
-            if stateful:
-                res_shapes = jax.eval_shape(
-                    lambda: init_uplink_residuals(
-                        codec, model.init(jax.random.PRNGKey(0)), cohort_tile
-                    )
-                )
-                args = args + (_tree_sds(res_shapes, client_pspecs, mesh),)
-                arg_names.append("residuals")
-            if partial_progress:
-                args = args + (_sds((cohort_tile,), jnp.int32, mesh, P()),)
-                arg_names.append("tau_steps")
-
-            def _tile(s, b, w, *rest):
-                kw = dict(zip(arg_names[1:], rest))
-                return run_client_tile(
-                    loss_fn, fed_tile, s, b, w,
-                    shard_clients=shard_clients, codec=codec, **kw,
-                )
-
-            # the server state is NOT donated (every tile of the round reads
-            # the same params snapshot); the tile's residual rows are replaced
-            # wholesale, so they are
-            donate = ()
-            if "residuals" in arg_names:
-                donate = (2 + arg_names.index("residuals"),)
-            step = jax.jit(_tile, donate_argnums=donate)
-            tokens_per_tile = tau_lowered * cohort_tile * (
-                shape.global_batch // C) * shape.seq_len
-            mf = 6.0 * cfg.active_param_count() * tokens_per_tile
-            return BuiltStep(
-                name=f"{cfg.name}:{shape.name}:federated-tile",
-                fn=step,
-                args=args,
-                model_flops=mf,
-                meta={
-                    "tau_lowered": tau_lowered,
-                    "tokens_per_call": tokens_per_tile,
-                    "clients": cohort_tile,
-                    "cohort_tile": cohort_tile,
-                    "grad_accum": ga,
-                    "client_axes": list(client_ax),
-                    "fsdp_axes": list(fsdp_ax),
-                    "elastic": elastic,
-                    "uplink": uplink,
-                    "partial_progress": partial_progress,
-                    "fused_server": False,
-                    "fused_server_requested": fused_server,
-                },
-            )
-        # elastic participation on the mesh: the (C,) weight vector enters the
-        # jitted round as a replicated traced input — dropouts / stragglers /
-        # K_eff < C on the production mesh never trigger a recompile, exactly
-        # like the CPU driver. All-ones weights are bitwise the flat round.
-        # The partial-progress τ-mask rides the same way: a replicated (C,)
-        # int32 input consumed inside the scan, so per-round realized step
-        # counts change freely without perturbing any sharding.
-        args = (state, batches)
-        arg_names = []
-        if elastic:
-            args = args + (_sds((C,), jnp.float32, mesh, P()),)
-            arg_names.append("client_weights")
-        if stateful:
-            # per-client error-feedback residuals ride the mesh exactly like the
-            # (C, ...) client-axis params replicas: same clientized pspecs, so
-            # the encoded-uplink round cannot perturb the parameter shardings
+            # the tile reads only the params/round/rng lanes; the outer
+            # optimizer state stays with the server program
+            state = {k: state[k] for k in ("params", "round", "rng")}
+        residuals = None
+        if codec is not None and codec.stateful:
+            # per-client error-feedback rows ride the client axis exactly like
+            # the (C, ...) params replicas: same clientized pspecs
             res_shapes = jax.eval_shape(
                 lambda: init_uplink_residuals(
-                    codec, model.init(jax.random.PRNGKey(0)), C
+                    codec, model.init(jax.random.PRNGKey(0)), width
                 )
             )
-            args = args + (_tree_sds(res_shapes, client_pspecs, mesh),)
-            arg_names.append("residuals")
-        if partial_progress:
-            args = args + (_sds((C,), jnp.int32, mesh, P()),)
-            arg_names.append("tau_steps")
-
-        def _round(s, b, *rest):
-            kw = dict(zip(arg_names, rest))
-            return federated_round(
-                loss_fn, fed, s, b, shard_clients=shard_clients, codec=codec,
-                apply_fn=apply_fn, **kw,
-            )
-
-        # donate the server state (params + outer lanes + rng) and, when
-        # present, the cohort residual rows: both are replaced wholesale every
-        # round, so the round stops double-buffering its params-sized arrays
-        donate = (0,)
-        if "residuals" in arg_names:
-            donate = donate + (2 + arg_names.index("residuals"),)
-        step = jax.jit(_round, donate_argnums=donate)
-        tokens_per_round = tau_lowered * shape.global_batch * shape.seq_len
-        mf = 6.0 * cfg.active_param_count() * tokens_per_round
+            residuals = _tree_sds(res_shapes, client_pspecs, mesh)
+        # the (C,) weights and τ-mask enter as replicated traced inputs, so
+        # participation and realized step counts never perturb a sharding
+        args = (state, batches, _sds((width,), jnp.float32, mesh, P()),
+                residuals, _sds((width,), jnp.int32, mesh, P()))
+        programs = build_round_programs(
+            loss_fn, fed, codec=codec, cohort_tile=cohort_tile,
+            shard_clients=shard_clients,
+        )
+        tokens = tau_lowered * width * (shape.global_batch // C) * shape.seq_len
+        meta = {
+            "tau_lowered": tau_lowered,
+            "tokens_per_call": tokens,
+            "clients": width,
+            "grad_accum": ga,
+            "client_axes": list(client_ax),
+            "fsdp_axes": list(fsdp_ax),
+            "uplink": uplink,
+        }
+        if cohort_tile is not None:
+            meta["cohort_tile"] = cohort_tile
         return BuiltStep(
-            name=f"{cfg.name}:{shape.name}:federated",
-            fn=step,
+            name=f"{cfg.name}:{shape.name}:federated"
+            + ("-tile" if cohort_tile is not None else ""),
+            fn=programs.tile if cohort_tile is not None else programs.round,
             args=args,
-            model_flops=mf,
-            meta={
-                "tau_lowered": tau_lowered,
-                "tokens_per_call": tokens_per_round,
-                "clients": C,
-                "grad_accum": ga,
-                "client_axes": list(client_ax),
-                "fsdp_axes": list(fsdp_ax),
-                "elastic": elastic,
-                "uplink": uplink,
-                "partial_progress": partial_progress,
-                "fused_server": fused_active,
-                "fused_server_requested": fused_server,
-            },
+            model_flops=6.0 * cfg.active_param_count() * tokens,
+            meta=meta,
         )
 
     # centralized baseline: per-step gradient sync (the paper's comparison).

@@ -155,6 +155,7 @@ from repro.core import (
     make_byzantine_fn,
     plan_round,
 )
+from repro.core.round_program import check_round_options
 from repro.data import build_client_streams, round_batches, validation_stream
 from repro.launch.compile_env import CompileCounter, enable_compile_cache
 from repro.metrics import (
@@ -602,26 +603,15 @@ def run(args, cfg=None) -> dict:
             "--rollback restores the server from the last good checkpoint — "
             "it requires --ckpt-dir"
         )
-    if robust is not None and robust.active and args.fused_server:
-        raise SystemExit(
-            "--robust-agg/--screen and --fused-server are mutually exclusive: "
-            "the fused Pallas server path computes the plain weighted mean "
-            "in one pass and has no robust-rule variant (docs/robustness.md)"
+    try:
+        # the round builder's rules, refused before anything is built
+        # (--cohort-tile under async is refused below: sync only)
+        check_round_options(
+            fed, robust=robust, fused_server=args.fused_server,
+            cohort_tile=args.cohort_tile if args.aggregation == "sync" else None,
         )
-    if robust is not None and args.cohort_tile:
-        if robust.screen:
-            raise SystemExit(
-                "--screen needs the whole cohort's delta norms at once and "
-                "cannot compose with --cohort-tile streaming; use "
-                "--robust-agg trimmed/median (tiled per-coordinate folds) "
-                "or normclip with an absolute --clip-norm"
-            )
-        if robust.rule == "normclip" and robust.clip_norm <= 0.0:
-            raise SystemExit(
-                "--robust-agg normclip under --cohort-tile needs an absolute "
-                "--clip-norm: the median-derived threshold (--clip-mult) "
-                "requires every cohort norm before any tile is folded"
-            )
+    except ValueError as e:
+        raise SystemExit(str(e))
     if args.byzantine_fraction > 0.0 and (
         args.aggregation != "async" or args.runtime != "inproc"
     ):

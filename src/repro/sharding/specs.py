@@ -19,13 +19,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# §Perf experiment toggle: replicate (instead of head_dim-sharding) small KV
-# projections — removes the per-layer q/kv resharding collective for GQA archs whose
-# kv-head count is below the model-axis size. REPRO_KV_REPLICATE=1.
-import os
-
-_KV_REPLICATE = os.environ.get("REPRO_KV_REPLICATE", "0") == "1"
-
 # logical axis -> preferred mesh axis (training / generic tensors)
 AXIS_RULES = {
     "vocab": "model",
@@ -125,8 +118,7 @@ def param_pspec(mesh: Mesh, axes: Tuple[Optional[str], ...], shape: Tuple[int, .
     # parameter mass stays distributed.
     if "model" not in used and "model" in mesh.axis_names:
         n = _axis_size(mesh, "model")
-        head_axes = ("heads",) if _KV_REPLICATE else ("heads", "kv_heads")
-        wants_model = any(a in head_axes for a in axes)
+        wants_model = any(a in ("heads", "kv_heads") for a in axes)
         if wants_model:
             for i, (logical, dim) in enumerate(zip(axes, shape)):
                 if logical == "head_dim" and dim % n == 0:

@@ -27,6 +27,7 @@ from repro.core import (
     FederatedConfig,
     OuterOptConfig,
     ParticipationConfig,
+    RobustAggConfig,
     SparseResidualStore,
     SyncAggregator,
     TopKCodec,
@@ -134,21 +135,30 @@ def test_tiled_round_uneven_tiles_match_flat(tile):
         )
 
 
-def test_cohort_tile_rejects_fused_server_and_keep_opt():
-    fed = _fed(2, 2)
-    pcfg = ParticipationConfig(population=4, clients_per_round=2)
-    with pytest.raises(ValueError, match="fused-server"):
-        SyncAggregator(
-            quad_loss, fed, pcfg, params=make_params(), cohort_tile=2,
-            fused_server=True,
-        )
+@pytest.mark.parametrize(
+    "options,match",
+    [
+        ({"cohort_tile": 2, "fused_server": True}, "fused-server"),
+        ({"cohort_tile": 2, "keep_inner_state": True}, "inner state"),
+        ({"fused_server": True, "robust": RobustAggConfig(rule="median")},
+         "robust rule"),
+        ({"cohort_tile": 2, "robust": RobustAggConfig(screen=True)}, "screen"),
+        ({"cohort_tile": 2, "robust": RobustAggConfig(rule="normclip")},
+         "absolute --clip-norm"),
+    ],
+    ids=["fused-tile", "keep-opt-tile", "fused-robust", "screen-tile",
+         "adaptive-normclip-tile"],
+)
+def test_cohort_tile_rejects_fused_server_and_keep_opt(options, match):
+    """The round builder's five compatibility rules, each refused when the
+    aggregator is built (before any state is allocated)."""
     from dataclasses import replace
 
-    with pytest.raises(ValueError, match="inner state"):
-        SyncAggregator(
-            quad_loss, replace(fed, keep_inner_state=True), pcfg,
-            params=make_params(), cohort_tile=2,
-        )
+    options = dict(options)
+    fed = replace(_fed(2, 2), keep_inner_state=options.pop("keep_inner_state", False))
+    pcfg = ParticipationConfig(population=4, clients_per_round=2)
+    with pytest.raises(ValueError, match=match):
+        SyncAggregator(quad_loss, fed, pcfg, params=make_params(), **options)
 
 
 # ---------------------------------------------------------------------------

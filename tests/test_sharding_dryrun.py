@@ -141,54 +141,18 @@ def test_reduced_dryrun_multi_pod():
 
 
 @pytest.mark.slow
-def test_weighted_round_compiles_under_flat_round_shardings():
-    """Mesh-elastic rounds (ROADMAP): the federated round with the (C,)
-    participation-weight input must compile on the mesh with the same memory
-    footprint, bottleneck, and (to within the weight vector's negligible
-    arithmetic) the same FLOPs and collective traffic as the legacy flat-mean
-    round — the weights ride along as a replicated traced input, they must not
-    perturb the parameter/batch shardings."""
-    flat = _run_dryrun("qwen3-1.7b", "train_4k", "(4, 4)", "('data', 'model')",
-                       kw={"mode": "federated", "elastic": False})
-    weighted = _run_dryrun("qwen3-1.7b", "train_4k", "(4, 4)", "('data', 'model')",
-                           kw={"mode": "federated", "elastic": True})
-    assert weighted["bottleneck"] == flat["bottleneck"]
-    assert weighted["flops"] == pytest.approx(flat["flops"], rel=0.01)
-    assert weighted["coll"] == pytest.approx(flat["coll"], rel=0.01)
-    assert weighted["mem"] == pytest.approx(flat["mem"], rel=0.02)
-
-
-@pytest.mark.slow
-def test_partial_progress_mask_lowers_without_sharding_perturbation():
-    """Straggler partial progress on the mesh (ISSUE 4): the federated round
-    with the (C,) τ-mask input must compile with the same bottleneck, FLOPs,
-    collective traffic and footprint as the plain elastic round — the realized
-    step counts ride along as a replicated traced int32 vector consumed inside
-    the scan, and must not perturb the parameter/batch shardings."""
-    base = _run_dryrun("qwen3-1.7b", "train_4k", "(4, 4)", "('data', 'model')",
-                       kw={"mode": "federated", "elastic": True})
-    partial = _run_dryrun("qwen3-1.7b", "train_4k", "(4, 4)", "('data', 'model')",
-                          kw={"mode": "federated", "elastic": True,
-                              "partial_progress": True})
-    assert partial["bottleneck"] == base["bottleneck"]
-    assert partial["flops"] == pytest.approx(base["flops"], rel=0.01)
-    assert partial["coll"] == pytest.approx(base["coll"], rel=0.01)
-    assert partial["mem"] == pytest.approx(base["mem"], rel=0.02)
-
-
-@pytest.mark.slow
 def test_compressed_uplink_lowers_without_sharding_perturbation():
     """Compressed uplink on the mesh (ROADMAP): the federated round with an
     uplink codec must compile with the same bottleneck and essentially the same
-    footprint as the uncompressed elastic round — the encoded-delta dtypes ride
+    footprint as the uncompressed round — the encoded-delta dtypes ride
     between the two phases and the (C, ...) error-feedback residuals enter under
     the client-axis pspecs, neither perturbing the parameter/batch shardings."""
     base = _run_dryrun("qwen3-1.7b", "train_4k", "(4, 4)", "('data', 'model')",
-                       kw={"mode": "federated", "elastic": True})
+                       kw={"mode": "federated"})
     bf16 = _run_dryrun("qwen3-1.7b", "train_4k", "(4, 4)", "('data', 'model')",
-                       kw={"mode": "federated", "elastic": True, "uplink": "bf16"})
+                       kw={"mode": "federated", "uplink": "bf16"})
     topk = _run_dryrun("qwen3-1.7b", "train_4k", "(4, 4)", "('data', 'model')",
-                       kw={"mode": "federated", "elastic": True, "uplink": "topk",
+                       kw={"mode": "federated", "uplink": "topk",
                            "topk_fraction": 0.05})
     assert bf16["bottleneck"] == base["bottleneck"]
     assert bf16["flops"] == pytest.approx(base["flops"], rel=0.01)
@@ -199,26 +163,6 @@ def test_compressed_uplink_lowers_without_sharding_perturbation():
     assert topk["bottleneck"] == base["bottleneck"]
     assert topk["flops"] >= base["flops"]
     assert topk["mem"] <= base["mem"] * 1.25
-
-
-@pytest.mark.slow
-def test_fused_server_flag_is_sharding_neutral_on_mesh():
-    """--fused-server dry-run smoke (ISSUE 5): the fused flat-buffer server
-    phase is the aggregator-host path — its kernel consumes the whole (C, N)
-    delta buffer and cannot span a GSPMD-sharded client axis, so on multi-device
-    meshes `build_train_step` keeps the reference server phase. This test pins
-    that contract: requesting --fused-server on the mesh must leave the
-    bottleneck, FLOPs, collective traffic and memory footprint EXACTLY as the
-    baseline lowering (identical HLO, not merely close)."""
-    base = _run_dryrun("qwen3-1.7b", "train_4k", "(4, 4)", "('data', 'model')",
-                       kw={"mode": "federated", "elastic": True})
-    fused = _run_dryrun("qwen3-1.7b", "train_4k", "(4, 4)", "('data', 'model')",
-                        kw={"mode": "federated", "elastic": True,
-                            "fused_server": True})
-    assert fused["bottleneck"] == base["bottleneck"]
-    assert fused["flops"] == base["flops"]
-    assert fused["coll"] == base["coll"]
-    assert fused["mem"] == base["mem"]
 
 
 TILE_SNIPPET = r"""
@@ -290,7 +234,7 @@ def test_cohort_tile_step_shardings_and_memory_flat_in_population():
     client-axis sharding; (c) a tile the width of the flat round's cohort
     costs no more device memory than the flat round itself (the tile emits
     partial sums instead of the (C, N) delta buffer + server phase)."""
-    base_kw = {"mode": "federated", "elastic": True, "uplink": "topk",
+    base_kw = {"mode": "federated", "uplink": "topk",
                "topk_fraction": 0.05}
     flat = _run_tile_dryrun(base_kw)
     tile_eq = _run_tile_dryrun({**base_kw, "cohort_tile": flat["clients"]})
