@@ -65,33 +65,35 @@ from repro.core.inner_opt import (
     inner_update,
 )
 from repro.core.outer_opt import OuterOptConfig, init_outer_state, outer_update
-from repro.models.moe import HELD_LOAD, ROUTER_LOAD
+from repro.models.moe import HELD_LOAD, ROUTED_ROWS, ROUTER_LOAD
 
 
 # the expert-share layers' per-step counters, averaged over a round's steps
-ROUTER_COUNTERS = ("moe_local_slots", "moe_load_max_over_mean")
+ROUTER_COUNTERS = ("moe_local_slots", "moe_load_max_over_mean", "moe_routed_rows")
 # loss metrics summed over a step's micro-batches rather than averaged
-SUMMED_METRICS = (ROUTER_LOAD, HELD_LOAD)
+SUMMED_METRICS = (ROUTER_LOAD, HELD_LOAD, ROUTED_ROWS)
 
 
-def load_counters(held: jax.Array) -> Dict[str, jax.Array]:
+def load_counters(held: jax.Array, rows: jax.Array) -> Dict[str, jax.Array]:
     """``ROUTER_COUNTERS`` from a step's slots per held expert, (layers, held),
-    averaged over the layers: the slots routed to the held experts, and the
-    held experts' largest load over their mean."""
+    and rows its routed parts were sized for, (layers,), averaged over the
+    layers: the slots routed to the held experts, the held experts' largest
+    load over their mean, and the rows."""
     local = jnp.sum(held, -1)
     return {
         "moe_local_slots": jnp.mean(local),
         "moe_load_max_over_mean": jnp.mean(
             jnp.max(held, -1) / jnp.maximum(local / held.shape[-1], 1e-9)),
+        "moe_routed_rows": jnp.mean(rows),
     }
 
 
 def _take_router_loads(metrics: Dict[str, Any]):
     """Takes the expert-share layers' loads out of a step's loss metrics (in
     place), adds their counters, and returns the loads for the load rule."""
-    held = metrics.pop(HELD_LOAD, None)
+    held, rows = metrics.pop(HELD_LOAD, None), metrics.pop(ROUTED_ROWS, None)
     if held is not None:
-        metrics.update(load_counters(held))
+        metrics.update(load_counters(held, rows))
     return metrics.pop(ROUTER_LOAD, None)
 
 

@@ -258,6 +258,8 @@ class Model:
         if aux["router_load"]:  # a step's slots per expert, for the load rule and counters
             metrics[moe_mod.ROUTER_LOAD] = aux["router_load"]
             metrics[moe_mod.HELD_LOAD] = moe_mod.held_loads(self.cfg, aux["router_load"])
+            metrics[moe_mod.ROUTED_ROWS] = jnp.concatenate(
+                [r.reshape(-1) for r in jax.tree_util.tree_leaves(aux["routed_rows"])])
         metrics["loss"] = loss
         return loss, metrics
 

@@ -15,6 +15,7 @@ in for it.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -29,6 +30,7 @@ HIGHEST = jax.lax.Precision.HIGHEST
 # client step takes them out before the metrics are reduced
 ROUTER_LOAD = "router_load"  # a tree at the router biases' paths, (…, n_experts)
 HELD_LOAD = "router_held_load"  # (MoE layers, held experts)
+ROUTED_ROWS = "router_routed_rows"  # (MoE layers,) rows the routed parts were sized for
 
 
 def dense_ffn_desc(cfg, d_ff: int, n_copies: int = 1) -> dict:
@@ -184,11 +186,13 @@ def _tile(n: int, candidates) -> int:
 
 def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array, first: int,
                    interpret=None) -> jax.Array:
-    """``x`` (m, k) rows sorted by group, ``group_sizes`` (G,) int32 summing to m,
-    ``w`` (g, k, n) the weights of groups ``[first, first + g)``: each of those
-    groups' rows times its own weights, and zero rows for every other group,
-    whose tiles the kernel skips. The megablox ``gmm`` kernel with its backward
-    (interpret mode on a CPU host)."""
+    """``x`` (m, k) rows sorted by group, ``group_sizes`` (G,) int32, ``w`` (g, k, n)
+    the weights of groups ``[first, first + g)``: each of those groups' rows
+    times its own weights. The kernel skips every other row's tiles. Where
+    ``g < G`` the other groups' rows are zeroed; where ``g == G`` the rows past
+    ``Σ group_sizes`` are never written and hold whatever the buffer held, so a
+    caller masks them (``jnp.where``, as a product with 0 keeps a NaN). The
+    megablox ``gmm`` kernel with its backward (interpret mode on a CPU host)."""
     from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
     if interpret is None:
@@ -200,21 +204,131 @@ def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array, first: in
                         jnp.asarray(first, jnp.int32), None, False, interpret)
 
 
+def trip_rows(tokens: int, top_k: int, held: int, n_experts: int) -> int:
+    """R, the rows of one trip of the routed part: twice the held experts' even
+    share of the ``tokens · top_k`` slots, rounded up to 512, and at most all
+    of them (one trip where every expert is held)."""
+    slots = tokens * top_k
+    return min(slots, -(-2 * slots * held // (n_experts * 512)) * 512)
+
+
+def _trip(c, rows: int, tok, gate, sizes):
+    """Rows ``[c·R, c·R + R)`` of the live run: each row's token and gate, whether
+    it is live, and the held experts' group sizes within the trip. Rows past
+    the run keep a real token and take a zero gate."""
+    with jax.named_scope("dispatch"):
+        start = c * rows
+        ends = jnp.clip(jnp.cumsum(sizes) - start, 0, rows)
+        live = jnp.arange(rows) < ends[-1]
+        gate = jnp.where(live, jax.lax.dynamic_slice_in_dim(gate, start, rows), 0.0)
+        return (jax.lax.dynamic_slice_in_dim(tok, start, rows), gate, live,
+                jnp.diff(ends, prepend=0).astype(jnp.int32))
+
+
+def _trip_experts(act, xs, g, live, w_in, w_gate, w_out, sizes):
+    """A trip's rows through their held experts' SwiGLU, times their gates, in
+    float32; 0 on rows that are not live, whatever the kernel left there."""
+    with jax.named_scope("experts"):
+        mm = lambda a, w: grouped_matmul(a, w, sizes, 0)  # noqa: E731
+        ys = mm(act(mm(xs, w_gate)) * mm(xs, w_in), w_out)
+        ys = jnp.where(live[:, None], ys, 0).astype(jnp.float32)
+    with jax.named_scope("combine"):
+        return ys * g.astype(xs.dtype).astype(jnp.float32)[:, None]
+
+
+def _trips(rows: int, sizes):
+    """The trips the live run of ``Σ sizes`` rows takes, at least one."""
+    return jnp.maximum(1, -(-jnp.sum(sizes) // rows))
+
+
+def _over_trips(rows: int, sizes, trip, init):
+    """``trip(c, carry)`` over the live run's trips, at least one. The first runs
+    outside the loop, so that XLA can fold its sums into ``init``'s zeros."""
+    return jax.lax.fori_loop(1, _trips(rows, sizes), trip, trip(0, init))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed(rows, act, xt, gate, w_in, w_gate, w_out, tok, sizes):
+    """The held experts' part of the layer, (T, D): the live run's rows (``tok``
+    and ``gate`` in sorted order, ``sizes`` the held experts' groups at its
+    front), ``rows`` at a time, each trip gathered, computed and added into a
+    float32 ``(T, D)`` sum by token."""
+    with jax.named_scope("experts"):
+        w = [v.astype(xt.dtype) for v in (w_in, w_gate, w_out)]
+
+    def trip(c, acc):
+        t, g, live, gs = _trip(c, rows, tok, gate, sizes)
+        with jax.named_scope("dispatch"):
+            xs = xt[t]
+        ys = _trip_experts(act, xs, g, live, *w, gs)
+        with jax.named_scope("combine"):
+            return acc.at[t].add(ys)
+
+    return _over_trips(rows, sizes, trip, jnp.zeros(xt.shape, jnp.float32)).astype(xt.dtype)
+
+
+def _routed_fwd(rows, act, xt, gate, w_in, w_gate, w_out, tok, sizes):
+    res = (xt, gate, w_in, w_gate, w_out, tok, sizes)
+    return _routed(rows, act, *res), res
+
+
+def _routed_bwd(rows, act, res, dy):
+    """The same trips again: each gathers ``dy``'s and ``x``'s rows, recomputes
+    its products and takes their VJP, adding ``dx`` rows into a float32 ``(T, D)``
+    sum, the weights' gradients into float32 sums, and writing the live rows'
+    gate gradients."""
+    xt, gate, w_in, w_gate, w_out, tok, sizes = res
+    ws = (w_in, w_gate, w_out)
+    with jax.named_scope("experts"):
+        w = [v.astype(xt.dtype) for v in ws]
+
+    def trip(c, carry):
+        dx, dgate, dw = carry
+        t, g, live, gs = _trip(c, rows, tok, gate, sizes)
+        with jax.named_scope("combine"):
+            dys = dy[t].astype(jnp.float32)
+        with jax.named_scope("dispatch"):
+            xs = xt[t]
+        _, vjp = jax.vjp(lambda xs, g, *w: _trip_experts(act, xs, g, live, *w, gs), xs, g, *w)
+        dxs, dg, *dw_t = vjp(dys)
+        with jax.named_scope("dispatch"):
+            dx = dx.at[t].add(jnp.where(live[:, None], dxs, 0).astype(jnp.float32))
+        with jax.named_scope("combine"):  # 0 on rows that are not live: ys is 0 there
+            dgate = jax.lax.dynamic_update_slice_in_dim(dgate, dg, c * rows, 0)
+        with jax.named_scope("experts"):
+            return dx, dgate, [a + b.astype(jnp.float32) for a, b in zip(dw, dw_t)]
+
+    dx, dgate, dw = _over_trips(rows, sizes, trip, (
+        jnp.zeros(xt.shape, jnp.float32), jnp.zeros_like(gate),
+        [jnp.zeros(v.shape, jnp.float32) for v in ws]))
+    return (dx.astype(xt.dtype), dgate, *[d.astype(v.dtype) for d, v in zip(dw, ws)],
+            None, None)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
 def moe_share_ffn(cfg, p: dict, x: jax.Array):
-    """Returns ``(y, balance, load)``: the layer's output (B, S, D) from the held
-    experts and the shared ones; the sequence-wise balance loss, the mean over
-    sequences of ``Σ_i f_i·P_i`` with ``f_i = E/(K·S)·#{t : i chosen for t}``
-    and ``P_i`` the mean over t of ``s_i,t / Σ_j s_j,t``; and the step's slots
-    routed to each of the ``n_experts`` experts, float32 (E,).
+    """Returns ``(y, balance, load, rows)``: the layer's output (B, S, D) from the
+    held experts and the shared ones; the sequence-wise balance loss, the mean
+    over sequences of ``Σ_i f_i·P_i`` with ``f_i = E/(K·S)·#{t : i chosen for
+    t}`` and ``P_i`` the mean over t of ``s_i,t / Σ_j s_j,t``; the step's slots
+    routed to each of the ``n_experts`` experts, float32 (E,); and the rows the
+    routed part was sized for, float32.
 
     Routing, per token, in float32: ``s = sigmoid(h·W_r)``, the top-K of
     ``s + b`` chosen (``b`` the correction bias), gates ``g_i = scale · s_i /
-    Σ_chosen s_j``. Every slot whose expert is held goes to it."""
+    Σ_chosen s_j``. Every slot whose expert is held goes to it. Those live
+    slots are sorted to the front, grouped by held expert, and only they are
+    gathered, computed and added back, ``trip_rows`` at a time (``_routed``):
+    no tensor of all T·K slots' rows is built."""
     B, S, D = x.shape
-    E, K, first = cfg.n_experts, cfg.moe_top_k, cfg.moe_first_expert
+    E, K, first, held = cfg.n_experts, cfg.moe_top_k, cfg.moe_first_expert, cfg.moe_experts_held
     T = B * S
     xt = x.reshape(T, D)
     act = activation_fn(cfg.activation)
+    rows = trip_rows(T, K, held, E)
+    pad = -(-T * K // rows) * rows - T * K  # the last trip's rows past the slots
     with jax.named_scope("moe"):
         with jax.named_scope("route"):
             s = jax.nn.sigmoid(jnp.einsum(
@@ -229,22 +343,18 @@ def moe_share_ffn(cfg, p: dict, x: jax.Array):
             balance = jnp.mean(jnp.sum(f * share, axis=-1))
         with jax.named_scope("dispatch"):
             slots = idx.reshape(T * K)
-            order = jnp.argsort(slots, stable=True)  # slots grouped by expert
+            # the held experts' slots first, grouped by expert, in slot order
+            order = jnp.argsort((slots - first) % E, stable=True)
             group_sizes = jnp.bincount(slots, length=E).astype(jnp.int32)
-            xs = xt[order // K]
-        with jax.named_scope("experts"):
-            w = {k: p[k].astype(x.dtype) for k in ("w_in", "w_gate", "w_out")}
-            h = (act(grouped_matmul(xs, w["w_gate"], group_sizes, first))
-                 * grouped_matmul(xs, w["w_in"], group_sizes, first))
-            ys = grouped_matmul(h, w["w_out"], group_sizes, first)  # 0 where not held
-        with jax.named_scope("combine"):
-            back = jnp.zeros_like(order).at[order].set(jnp.arange(T * K, dtype=order.dtype))
-            y = jnp.einsum("tkd,tk->td", ys[back].reshape(T, K, D), gate.astype(x.dtype),
-                           preferred_element_type=jnp.float32).astype(x.dtype)
+            sizes = group_sizes[first:first + held]
+            tok = jnp.pad(order // K, (0, pad))
+            gate_sorted = jnp.pad(gate.reshape(T * K)[order], (0, pad))
+        y = _routed(rows, act, xt, gate_sorted, p["w_in"], p["w_gate"], p["w_out"], tok, sizes)
         if cfg.n_shared_experts:
             with jax.named_scope("shared"):
                 y = y + dense_ffn(cfg, p["shared"], xt)
-    return y.reshape(B, S, D), balance, group_sizes.astype(jnp.float32)
+    n_rows = (_trips(rows, sizes) * rows).astype(jnp.float32)
+    return y.reshape(B, S, D), balance, group_sizes.astype(jnp.float32), n_rows
 
 
 def held_loads(cfg, loads) -> jax.Array:

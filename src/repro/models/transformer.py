@@ -214,9 +214,10 @@ def _apply_layer(
     enc_out: Optional[jax.Array],
     decode: bool,
     use_pallas: bool,
-) -> Tuple[jax.Array, Optional[dict], jax.Array, Optional[jax.Array]]:
+) -> Tuple[jax.Array, Optional[dict], jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
     """Returns ``(h, new_cache, aux_loss, load)``: ``load`` is an expert-share
-    MoE layer's (E,) slots per expert (``moe.moe_share_ffn``), else None."""
+    MoE layer's (E,) slots per expert and the rows its routed part was sized
+    for (``moe.moe_share_ffn``), else None."""
     aux = jnp.zeros((), jnp.float32)
     load = None
     new_cache: Dict[str, Any] = {}
@@ -271,7 +272,8 @@ def _apply_layer(
         if kind.ffn == "dense":
             f = moe_mod.dense_ffn(cfg, p["ffn"], x2)
         elif cfg.moe_experts_held:
-            f, aux, load = moe_mod.moe_share_ffn(cfg, p["ffn"], x2)
+            f, aux, slots, rows = moe_mod.moe_share_ffn(cfg, p["ffn"], x2)
+            load = (slots, rows)
         else:
             f, aux = moe_mod.moe_ffn(cfg, p["ffn"], x2)
         h = h + f
@@ -336,7 +338,7 @@ def _apply_segment(
 
     def run_body(h, params_r, windows_r, cache_r):
         aux_total = jnp.zeros((), jnp.float32)
-        new_cache_r, loads_r = {}, {}
+        new_cache_r, loads_r, rows_r = {}, {}, {}
         for pidx, kind in enumerate(seg.kinds):
             key = f"pos{pidx}"
             h, nc, aux, load = layer_fns[pidx](
@@ -348,9 +350,10 @@ def _apply_segment(
             if nc is not None:
                 new_cache_r[key] = nc
             if load is not None:  # at the router bias's own path
-                loads_r[key] = {"ffn": {"router_bias": load}}
+                loads_r[key] = {"ffn": {"router_bias": load[0]}}
+                rows_r[key] = load[1]
             aux_total = aux_total + aux
-        return h, new_cache_r, aux_total, loads_r
+        return h, new_cache_r, aux_total, (loads_r, rows_r)
 
     if seg.n_repeat == 1:
         params_r = seg_params
@@ -435,9 +438,10 @@ def forward(
     logits_mode: str = "full",  # 'full' | 'last' | 'hidden' (return pre-head h)
 ):
     """Returns (logits (B,S,V) | hidden (B,S,D), aux, new_cache). ``aux`` holds
-    ``loss``, the MoE layers' summed auxiliary loss, and ``router_load``, the
+    ``loss``, the MoE layers' summed auxiliary loss, ``router_load``, the
     expert-share layers' slots per expert at the router biases' paths
-    (``{"segments": [...]}``; empty without such layers)."""
+    (``{"segments": [...]}``; empty without such layers), and ``routed_rows``,
+    those layers' rows their routed parts were sized for, in the same order."""
     assert mode in ("train", "prefill", "decode")
     decode = mode == "decode"
     B, S = tokens.shape
@@ -469,7 +473,7 @@ def forward(
         cache = _prefill_placeholder_cache(cfg, segs)
 
     aux_total = jnp.zeros((), jnp.float32)
-    loads = []
+    loads, rows = [], []
     new_cache = [] if (cache is not None or decode) else None
     for seg, seg_params, seg_cache in zip(
         segs, params["segments"], cache if cache is not None else [None] * len(segs)
@@ -489,12 +493,14 @@ def forward(
             remat=remat,
         )
         aux_total = aux_total + aux
-        loads.append(seg_loads)
+        loads.append(seg_loads[0])
+        rows.append(seg_loads[1])
         if new_cache is not None:
             new_cache.append(seg_new_cache)
 
     aux = {"loss": aux_total,
-           "router_load": {"segments": loads} if jax.tree_util.tree_leaves(loads) else {}}
+           "router_load": {"segments": loads} if jax.tree_util.tree_leaves(loads) else {},
+           "routed_rows": rows}
     h = apply_norm(cfg, params["final_norm"], h)
     if logits_mode == "hidden":
         return h, aux, new_cache

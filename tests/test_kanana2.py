@@ -124,7 +124,7 @@ def test_expert_shares_add_up_to_the_uncut_layer():
     total, loads = shared, []
     for first in (0, 4, 8, 12):
         part = dict(_moe_weights(5, cfg, first), router_bias=full["router_bias"])
-        y, _, load = moe.moe_share_ffn(dataclasses.replace(cfg, moe_first_expert=first), part, x)
+        y, _, load, _ = moe.moe_share_ffn(dataclasses.replace(cfg, moe_first_expert=first), part, x)
         total, loads = total + (y - shared), loads + [load]
     with jax.default_matmul_precision("highest"):
         want, _, want_load = ref.moe_share(
@@ -134,16 +134,68 @@ def test_expert_shares_add_up_to_the_uncut_layer():
         np.testing.assert_array_equal(np.asarray(load), np.asarray(want_load))
 
 
-def test_no_token_is_dropped_when_the_bias_sends_every_token_to_one_expert():
-    cfg = _cfg()
-    x = jax.random.normal(jax.random.PRNGKey(7), (2, S, SMALL["d_model"]))
-    p = _moe_weights(8, cfg, 4)
-    p["router_bias"] = jnp.zeros(16).at[5].set(100.0)  # expert 5, held here
-    y, _, load = moe.moe_share_ffn(cfg, p, x)
-    assert float(load[5]) == 2 * S  # every token: 8x the load of an even split
+def _layer_against_reference(over, B, bias, seed):
+    """The expert-share layer and the gradients of ``⟨y, ct⟩`` for its input and
+    weights, against the reference's, and the rows it reports, trips × R;
+    returns the slots routed to the held experts, R, the input, the weights,
+    the output and the gradients."""
+    c = dict(SMALL, **over)
+    cfg = _cfg(**over)
+    x = jax.random.normal(jax.random.PRNGKey(seed), (B, S, c["d_model"]))
+    p = init_params(jax.random.PRNGKey(seed + 1), moe.moe_share_desc(cfg))
+    p["router_bias"] = jnp.asarray(bias, jnp.float32)
+    ct = jax.random.normal(jax.random.PRNGKey(seed + 2), x.shape)
+    y, _, load, rows = moe.moe_share_ffn(cfg, p, x)
+    grads = jax.grad(lambda x, p: jnp.sum(moe.moe_share_ffn(cfg, p, x)[0] * ct),
+                     argnums=(0, 1))(x, p)
     with jax.default_matmul_precision("highest"):
-        want, _, _ = ref.moe_share(SMALL, F32, x, p)
+        want, _, want_load = ref.moe_share(c, F32, x, p)
+        want_grads = jax.grad(lambda x, p: jnp.sum(ref.moe_share(c, F32, x, p)[0] * ct),
+                              argnums=(0, 1))(x, p)
     _close(y, want)
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(want_load))
+    got, exp = _leaves(grads), _leaves(want_grads)
+    assert got.keys() == exp.keys()
+    for k in exp:
+        _close(got[k], exp[k], 2e-3)
+    first, held = c["moe_first_expert"], c["moe_experts_held"]
+    live = int(np.asarray(want_load)[first:first + held].sum())
+    R = moe.trip_rows(B * S, c["moe_top_k"], held, c["n_experts"])
+    assert float(rows) == max(1, -(-live // R)) * R  # trips × R
+    return live, R, (x, p, y, grads)
+
+
+def test_no_token_is_dropped_when_the_bias_sends_every_token_to_one_expert():
+    bias = np.zeros(16)
+    bias[5] = 100.0  # expert 5, held here
+    live, _, _ = _layer_against_reference({}, 2, bias, 7)
+    assert live >= 2 * S  # every token: 16/3 times an even split's load on expert 5
+
+
+# E 32, 4 held from expert 4, top-3, 512 tokens: R = 512 of the 1,536 slots
+WIDE = dict(n_experts=32)
+
+
+@pytest.mark.parametrize("over,B,bias,trips", [
+    (WIDE, 8, 0.01 * np.random.RandomState(9).randn(32), 1),  # in one trip
+    (WIDE, 8, np.where(np.arange(32) == 5, 100.0, 0.0), 2),  # every token on held expert 5
+    (WIDE, 8, np.where(np.arange(32) < 3, 100.0, 0.0), 0),  # every token on experts 0-2
+    (dict(moe_experts_held=16, moe_first_expert=0), 2, np.zeros(16), 1),  # the whole layer
+], ids=["one_trip", "several_trips", "no_live_slot", "whole_layer"])
+def test_routed_part_and_its_gradients_match_the_reference_in_every_trip_regime(
+        over, B, bias, trips):
+    """The routed part runs the live slots R rows at a time: within one trip,
+    over several, with none live (the shared experts alone), and with every
+    expert held (R = T·K)."""
+    live, R, (x, p, y, (_, dp)) = _layer_against_reference(over, B, bias, 13)
+    assert max(1, -(-live // R)) == max(trips, 1) and (live == 0) == (trips == 0)
+    if "moe_experts_held" in over:
+        assert R == B * S * SMALL["moe_top_k"]
+    if not live:  # the shared experts' output alone; no gradient reaches the routed part
+        shared = moe.dense_ffn(_cfg(**over), p["shared"], x)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(shared))
+        for k in ("w_in", "w_gate", "w_out", "router"):
+            assert not np.any(np.asarray(dp[k])), k
 
 
 @pytest.mark.parametrize("optimizer,grad_accum", [("adamw", 1), ("sgd", 1), ("adamw", 2)])
@@ -181,3 +233,6 @@ def test_load_rule_moves_the_bias_and_the_optimizer_leaves_it_alone(optimizer, g
     np.testing.assert_allclose(float(metrics["moe_local_slots"]), held.sum(-1).mean())
     np.testing.assert_allclose(float(metrics["moe_load_max_over_mean"]),
                                np.mean(held.max(-1) / (held.sum(-1) / 4)), rtol=1e-6)
+    # one trip of R rows a micro-batch (R = T·K at this size), summed over the step
+    R = moe.trip_rows(2 * S // grad_accum, SMALL["moe_top_k"], 4, SMALL["n_experts"])
+    assert float(metrics["moe_routed_rows"]) == grad_accum * R == 2 * S * SMALL["moe_top_k"]

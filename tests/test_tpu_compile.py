@@ -1,6 +1,7 @@
 """Real-width TPU v5e compiles of the fedcore kernels (kernels/fedcore/kernel.py),
-of the flash attention kernels (kernels/flash_attention/) and of the grouped
-expert product (models/moe.grouped_matmul, the megablox kernels).
+of the flash attention kernels (kernels/flash_attention/), of the grouped
+expert product (models/moe.grouped_matmul, the megablox kernels) and of the
+expert-share layer around it (models/moe.moe_share_ffn).
 
 The interpret-mode parity tests (tests/test_fed_kernels.py, tests/test_kernels.py)
 run the kernels' Python bodies on the CPU, which accepts programs the TPU
@@ -149,3 +150,36 @@ def test_grouped_expert_product_compiles_for_v5e(one_chip):
               ((E,), jnp.int32), ((T * K, d), jnp.bfloat16)]
     text = _compile(fwd_bwd, one_chip, *shapes).as_text()
     assert text.count('custom_call_target="tpu_custom_call"') >= 3 * 3  # gmm, and tgmm + gmm back
+
+
+def test_expert_share_layer_compiles_for_v5e_with_no_tensor_of_every_slot(one_chip, monkeypatch):
+    """kanana-2-30b-a3b's expert-share layer as its cell runs it (T 4096, top-6 of
+    128 experts, 8 held, d 2048, SwiGLU 768), forward and backward, the
+    grouped products compiled as kernels: the routed part moves only the live
+    slots' rows, R = 3,072 at a time, so no float array of all T·K = 24,576
+    slots' rows is built."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.models import moe
+    from repro.models.common import is_desc
+
+    monkeypatch.setattr(moe, "_on_cpu", lambda: False)  # the kernels, not their interpreter
+    cfg = dataclasses.replace(get_config("kanana-2-30b-a3b"), moe_experts_held=8,
+                              moe_first_expert=0, compute_dtype="bfloat16")
+    assert moe.trip_rows(4096, 6, 8, 128) == 3072
+    p = jax.tree_util.tree_map(
+        lambda d: jax.ShapeDtypeStruct(d.shape, jnp.float32, sharding=one_chip),
+        moe.moe_share_desc(cfg), is_leaf=is_desc)
+    x = jax.ShapeDtypeStruct((1, 4096, 2048), jnp.bfloat16, sharding=one_chip)
+
+    def fwd_bwd(p, x, dy):
+        y, vjp = jax.vjp(lambda p, x: moe.moe_share_ffn(cfg, p, x)[0], p, x)
+        return y, vjp(dy)
+
+    text = jax.jit(fwd_bwd).lower(p, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 3 * 3
+    every_slot = re.findall(r"(?:bf16|f16|f32)\[(?:\d+,)?(?:24576,(?:2048|768)|4096,6,2048)\]",
+                            text)
+    assert not every_slot, sorted(set(every_slot))
